@@ -311,51 +311,52 @@ class ShrinkEngine:
         exact branch, which also reseeds the carry with the true top-K
         basis.
         """
-        if not self.lazy:
-            W, nn, _, _ = self._exact_shrink(M, tau)
-            return W, nn, carry
+        with jax.named_scope("shrink"):
+            if not self.lazy:
+                W, nn, _, _ = self._exact_shrink(M, tau)
+                return W, nn, carry
 
-        K = self.K
-        U, V, R, _ = _sweeps(M, carry["V"], carry["s"],
-                             self.max_sweeps, self.drift_tol)
-        Ur, s, Vr = _ritz_from_R(U, V, R)
-        shr = jnp.maximum(s - tau, 0.0)
-        scale = jnp.maximum(s[0], _TINY)
-        # explicit deflation: everything the block failed to capture
-        E = M - (Ur * s[None, :]) @ Vr.T
-        res = _residuals(E, Ur, Vr)
-        # Shrink-weighted convergence: a triplet enters the output with
-        # weight (s_i − τ)₊, so its subspace error matters in that
-        # proportion — triplets hugging the threshold (the block
-        # boundary inside a noise bulk, which NEVER converges
-        # individually) are output-insensitive and must not block
-        # acceptance, while dominant signal triplets are held to the
-        # full tolerance.  (Ritz VALUES converge quadratically in the
-        # residual, so the (s_i − τ)₊ weights themselves are accurate
-        # well before the vectors are.)
-        conv_ok = jnp.max(res * shr / jnp.maximum(s, _TINY)) <= \
-            self.res_tol * scale
-        fro = jnp.linalg.norm(E)
-        t_est, Tb = _tail_power(E, carry["T"], self.tail_iters)
-        tail_ok = (fro <= self.fro_margin * tau) | \
-            (t_est <= self.tail_margin * tau)
-        good = (carry["warm"] > 0) & conv_ok & tail_ok
+            K = self.K
+            U, V, R, _ = _sweeps(M, carry["V"], carry["s"],
+                                 self.max_sweeps, self.drift_tol)
+            Ur, s, Vr = _ritz_from_R(U, V, R)
+            shr = jnp.maximum(s - tau, 0.0)
+            scale = jnp.maximum(s[0], _TINY)
+            # explicit deflation: everything the block failed to capture
+            E = M - (Ur * s[None, :]) @ Vr.T
+            res = _residuals(E, Ur, Vr)
+            # Shrink-weighted convergence: a triplet enters the output with
+            # weight (s_i − τ)₊, so its subspace error matters in that
+            # proportion — triplets hugging the threshold (the block
+            # boundary inside a noise bulk, which NEVER converges
+            # individually) are output-insensitive and must not block
+            # acceptance, while dominant signal triplets are held to the
+            # full tolerance.  (Ritz VALUES converge quadratically in the
+            # residual, so the (s_i − τ)₊ weights themselves are accurate
+            # well before the vectors are.)
+            conv_ok = jnp.max(res * shr / jnp.maximum(s, _TINY)) <= \
+                self.res_tol * scale
+            fro = jnp.linalg.norm(E)
+            t_est, Tb = _tail_power(E, carry["T"], self.tail_iters)
+            tail_ok = (fro <= self.fro_margin * tau) | \
+                (t_est <= self.tail_margin * tau)
+            good = (carry["warm"] > 0) & conv_ok & tail_ok
 
-        def lazy_branch(_):
-            return ((Ur * shr[None, :]) @ Vr.T, jnp.sum(shr), Vr, s,
-                    jnp.int32(0))
+            def lazy_branch(_):
+                return ((Ur * shr[None, :]) @ Vr.T, jnp.sum(shr), Vr, s,
+                        jnp.int32(0))
 
-        def exact_branch(_):
-            # one factorization serves both the shrink and the carry
-            # reseed (true top-K right subspace)
-            W, nn, S, Vt = self._exact_shrink(M, tau)
-            return W, nn, Vt[:K].T, S[:K], jnp.int32(1)
+            def exact_branch(_):
+                # one factorization serves both the shrink and the carry
+                # reseed (true top-K right subspace)
+                W, nn, S, Vt = self._exact_shrink(M, tau)
+                return W, nn, Vt[:K].T, S[:K], jnp.int32(1)
 
-        W, nn, Vc, sc, ex = jax.lax.cond(good, lazy_branch, exact_branch,
-                                         None)
-        return W, nn, {"V": Vc, "s": sc, "T": Tb,
-                       "warm": jnp.ones((), jnp.int32),
-                       "exact_rounds": carry["exact_rounds"] + ex}
+            W, nn, Vc, sc, ex = jax.lax.cond(good, lazy_branch, exact_branch,
+                                             None)
+            return W, nn, {"V": Vc, "s": sc, "T": Tb,
+                           "warm": jnp.ones((), jnp.int32),
+                           "exact_rounds": carry["exact_rounds"] + ex}
 
     @_f32_matmuls
     def project(self, M: jnp.ndarray, radius, carry: Carry

@@ -35,9 +35,14 @@ needs no reduction — the 2-D runtime rebuilds the cache as a psum of
 per-shard partial Grams before the round loop, so ``gram_A``/``gram_b``
 are already global.  ``rt=None`` keeps the historical single-shard
 behaviour bit-for-bit.
+
+Each per-round entry point runs under the ``worker`` named scope, as the
+runtime's ``worker_map`` does, so the lowered round program's op names
+tell the worker's ops from the master's.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -45,6 +50,15 @@ import jax.numpy as jnp
 
 from . import linear_model as lm
 from .losses import Loss
+
+
+def _worker_step(fn):
+    """Trace ``fn`` under the ``worker`` named scope."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope("worker"):
+            return fn(*args, **kwargs)
+    return scoped
 
 
 def gram_stats(Xs: jnp.ndarray, ys: jnp.ndarray, data_shards: int = 1
@@ -126,6 +140,7 @@ def _resolve_impl(loss: Loss, data: Dict[str, jnp.ndarray],
     return "xla"
 
 
+@_worker_step
 def grad_columns(loss: Loss, W_cols: jnp.ndarray,
                  data: Dict[str, jnp.ndarray], l2: float = 0.0,
                  impl: Optional[str] = None, rt=None) -> jnp.ndarray:
@@ -216,6 +231,7 @@ def _sample_batch(data: Dict[str, jnp.ndarray], rt, seed: int, round_k,
     return Xb, yb
 
 
+@_worker_step
 def minibatch_grad_columns(loss: Loss, W_cols: jnp.ndarray,
                            data: Dict[str, jnp.ndarray], l2: float = 0.0,
                            rt=None, *, seed: int, round_k, local_step,
@@ -250,6 +266,7 @@ def _resolve_step_impl(loss: Loss, impl: Optional[str]) -> str:
     return "xla"
 
 
+@_worker_step
 def minibatch_prox_step_columns(loss: Loss, W_cols: jnp.ndarray,
                                 data: Dict[str, jnp.ndarray],
                                 l2: float = 0.0, rt=None, *, seed: int,
@@ -302,6 +319,7 @@ def minibatch_prox_step_columns(loss: Loss, W_cols: jnp.ndarray,
     return _pmean(rt, W_new, "minibatch gradient shards")
 
 
+@_worker_step
 def minibatch_newton_columns(loss: Loss, W_cols: jnp.ndarray,
                              data: Dict[str, jnp.ndarray], l2: float = 0.0,
                              damping: float = 1e-6, rt=None, *, seed: int,
@@ -321,6 +339,7 @@ def minibatch_newton_columns(loss: Loss, W_cols: jnp.ndarray,
                     in_axes=(0, 1), out_axes=1)(H, g)
 
 
+@_worker_step
 def newton_columns(loss: Loss, W_cols: jnp.ndarray,
                    data: Dict[str, jnp.ndarray], l2: float = 0.0,
                    damping: float = 1e-6, rt=None) -> jnp.ndarray:
@@ -356,6 +375,7 @@ def newton_columns(loss: Loss, W_cols: jnp.ndarray,
         in_axes=(1, 0, 0), out_axes=1)(W_cols, data["Xs"], data["ys"])
 
 
+@_worker_step
 def ridge_columns(data: Dict[str, jnp.ndarray], l2: float) -> jnp.ndarray:
     """Per-task ridge solutions (p, L) from the Gram cache (squared loss).
 
@@ -394,6 +414,7 @@ def _newton_cols(loss: Loss, Xs: jnp.ndarray, ys: jnp.ndarray, l2: float,
     return jax.lax.fori_loop(0, iters, body, jnp.zeros((d, L), Xs.dtype))
 
 
+@_worker_step
 def erm_columns(loss: Loss, data: Dict[str, jnp.ndarray], l2: float,
                 rt=None, iters: int = 25) -> jnp.ndarray:
     """Per-task unconstrained ERM solutions (p, L) — the Local baseline's
@@ -418,6 +439,7 @@ def erm_columns(loss: Loss, data: Dict[str, jnp.ndarray], l2: float,
     return _newton_cols(loss, Xs, ys, l2, iters, rt)
 
 
+@_worker_step
 def prox_columns(loss: Loss, data: Dict[str, jnp.ndarray],
                  Z_cols: jnp.ndarray, Q_cols: jnp.ndarray,
                  W0_cols: jnp.ndarray, rho: float, m: int, l2: float = 0.0,
@@ -462,6 +484,7 @@ def prox_columns(loss: Loss, data: Dict[str, jnp.ndarray],
     return jax.lax.fori_loop(0, iters, newton, W0_cols)
 
 
+@_worker_step
 def projected_solves(loss: Loss, U: jnp.ndarray,
                      data: Dict[str, jnp.ndarray], l2: float = 0.0,
                      iters: int = 25, rt=None

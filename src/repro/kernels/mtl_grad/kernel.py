@@ -121,4 +121,5 @@ def task_gradients_mnp(X, y, W, *, loss: str = "squared", br=None,
         out_shape=jax.ShapeDtypeStruct((m, p), jnp.float32),
         scratch_shapes=[pltpu.VMEM((tb, p), jnp.float32)],
         interpret=interpret,
+        name="mtl_grad",
     )(X, y[..., None], W)

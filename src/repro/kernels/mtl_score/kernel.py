@@ -109,5 +109,6 @@ def mtl_score_fused(U, C, S, ids, X, *, bb: int = 128,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nb * bb,), jnp.float32),
         interpret=interpret,
+        name="mtl_score",
     )(ids, X, U, C, S)
     return out[:B]

@@ -82,4 +82,5 @@ def prox_step_lnp(X, y, W, Z, Q, params, *, loss: str = "squared",
         out_shape=jax.ShapeDtypeStruct((L, p), jnp.float32),
         scratch_shapes=[pltpu.VMEM((tb, p), jnp.float32)],
         interpret=interpret,
+        name="prox_step",
     )(X, y[..., None], W, Z, Q, params)

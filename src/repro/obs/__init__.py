@@ -7,9 +7,12 @@ Three pillars (DESIGN.md §15):
   carry into ``MTLResult.extras["metrics"]``; metrics off ⇒
   bit-identical solves, metrics on ⇒ bit-identical W + ledger;
 * host span tracing — :func:`trace_span` / :func:`emit_event` around
-  solve setup, checkpoint saves, resume/rollback, streaming refresh
-  phases, and server install/hot-swap/onboard, exportable as JSONL and
-  Chrome ``trace.json`` (:mod:`~repro.obs.tracing`);
+  solve calls (and, under a profiler trace, their staging), checkpoint
+  saves, resume/rollback, streaming refresh phases, and server
+  install/hot-swap/onboard, exportable as JSONL and Chrome
+  ``trace.json`` and present on a ``jax.profiler`` trace, plus
+  profiler-only :func:`annotate` spans around each scoring call
+  (:mod:`~repro.obs.tracing`);
 * serving SLO metrics — latency histograms / counters / staleness
   gauges in a shared :class:`MetricsRegistry` with JSONL + Prometheus
   snapshot exporters (:mod:`~repro.obs.metrics`).
@@ -24,15 +27,14 @@ from .metrics import (  # noqa: F401
     default_registry, device_bucket_counts,
 )
 from .tracing import (  # noqa: F401
-    Tracer, configure, default_tracer, emit_event, export_chrome_trace,
-    profiler_session, read_events_jsonl, trace_span,
+    Tracer, annotate, configure, default_tracer, emit_event,
+    export_chrome_trace, read_events_jsonl, trace_span,
 )
 
 __all__ = [
     "OBS_KEY", "RoundMetricsSink", "obs_init", "obs_round",
     "Counter", "Gauge", "LatencyHistogram", "MetricsRegistry",
     "bucket_edges", "default_registry", "device_bucket_counts",
-    "Tracer", "configure", "default_tracer", "emit_event",
-    "export_chrome_trace", "profiler_session", "read_events_jsonl",
-    "trace_span",
+    "Tracer", "annotate", "configure", "default_tracer", "emit_event",
+    "export_chrome_trace", "read_events_jsonl", "trace_span",
 ]

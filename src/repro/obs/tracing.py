@@ -1,13 +1,35 @@
-"""Host span tracing: where a solve/serve run spends its wall clock.
+"""Host span tracing: where a solve/serve run spends its time.
 
-A :class:`Tracer` keeps a bounded in-memory ring of events and,
-optionally, streams them to a JSONL file under a configured run
-directory.  Two event shapes share one schema:
+One tracer, two tiers, one clock.
 
-* spans — ``with trace_span("ckpt.save", step=25): ...`` records one
-  COMPLETE event at exit: start wall time + a ``perf_counter``-measured
-  duration (wall stamps order events on a timeline; durations never
-  come from the wall clock, so NTP slews can't corrupt them);
+* Lifecycle spans (ring + profiler) — ``with trace_span("ckpt.save",
+  step=25): ...``.  A :class:`Tracer` keeps a bounded in-memory ring of
+  events and, optionally, streams them to a JSONL file under a
+  configured run directory, so a run directory holds its own timeline
+  with no profiler running.  Each span also opens a
+  ``jax.profiler.TraceAnnotation`` of the same name (with its str, int,
+  float and bool attrs), so under a ``jax.profiler`` trace it lands on
+  the profiler's host plane, on the same clock as the device's
+  ``XLA Ops`` events.  These mark the rare, expensive phases: ``solve``
+  and, while a profiler trace records, its ``solve.trace`` /
+  ``solve.lower`` / ``solve.compile`` / ``solve.run`` stages
+  (``repro.runtime.base.round_jit``), checkpoint saves, server install
+  / hot-swap / onboard, streaming refreshes.
+* Per-call spans (profiler only) — ``with annotate("serve.score"):
+  ...`` is a ``TraceAnnotation`` and nothing else: no ring
+  append, no lock, no clock read.  It exists for boundaries crossed
+  hundreds of times a second (each ``MTLServer.score`` call and its
+  prepare / pad / dispatch / sync steps): in the ring they would evict
+  every lifecycle event within seconds and pay a locked append on
+  every call.  With no profiler trace running, entering and leaving
+  one costs about a microsecond.
+
+Two event shapes share the ring's schema:
+
+* spans — one COMPLETE event at exit: start wall time + a
+  ``perf_counter``-measured duration (wall stamps order events on a
+  timeline; durations never come from the wall clock, so NTP slews
+  can't corrupt them);
 * instants — ``emit_event("recovery.rollback", step=75)`` records a
   zero-duration marker.
 
@@ -20,14 +42,11 @@ tests/test_obs.py)::
 ``export_chrome_trace`` rewrites the ring (or a JSONL file) into the
 Chrome/Perfetto ``trace.json`` event format, so a run directory opens
 directly in ``chrome://tracing`` / https://ui.perfetto.dev.
-``profiler_session`` hands the same run directory to ``jax.profiler``
-for device-level timelines when the caller wants XLA's view next to
-the host spans.
 
 Everything here is host-side and allocation-light: an unconfigured
-tracer costs one deque append per span, and none of it runs inside
-jit (the device-resident metrics pillar rides the scan carry instead —
-DESIGN.md §15).
+tracer costs one deque append and one inactive annotation per span,
+and none of it runs inside jit (the device-resident metrics pillar
+rides the scan carry instead — DESIGN.md §15).
 """
 from __future__ import annotations
 
@@ -39,9 +58,11 @@ import time
 from collections import deque
 from typing import Any, Dict, Iterator, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
-    "Tracer", "trace_span", "emit_event", "default_tracer", "configure",
-    "export_chrome_trace", "read_events_jsonl", "profiler_session",
+    "Tracer", "trace_span", "annotate", "emit_event", "default_tracer",
+    "configure", "export_chrome_trace", "read_events_jsonl",
     "EVENTS_JSONL", "TRACE_JSON",
 ]
 
@@ -121,12 +142,15 @@ class Tracer:
     @contextlib.contextmanager
     def span(self, name: str, **attrs) -> Iterator[Dict[str, Any]]:
         """Time a block; the event records even when the block raises
-        (with ``attrs["error"]`` set to the exception type)."""
+        (with ``attrs["error"]`` set to the exception type).  The block
+        also runs under a profiler annotation of the same name."""
         t_wall = time.time()
         t0 = time.perf_counter()
         extra: Dict[str, Any] = {}
         try:
-            yield extra
+            with annotate(name, **{k: v for k, v in attrs.items()
+                                   if isinstance(v, (str, int, float))}):
+                yield extra
         except BaseException as e:
             extra["error"] = type(e).__name__
             raise
@@ -188,17 +212,11 @@ def read_events_jsonl(path: str) -> List[Dict[str, Any]]:
     return events
 
 
-@contextlib.contextmanager
-def profiler_session(log_dir: str):
-    """``jax.profiler`` hand-off: device-level timelines in the same
-    run directory as the host spans.  A trace that cannot start raises:
-    a measurement that silently lost its device timeline is wrong."""
-    import jax
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
+def annotate(name: str, **attrs) -> TraceAnnotation:
+    """A profiler-only span for per-call boundaries: ``with
+    annotate("serve.dispatch"): ...`` shows on a ``jax.profiler`` trace
+    and nowhere else (no ring event, no JSONL line)."""
+    return TraceAnnotation(name, **attrs)
 
 
 _DEFAULT = Tracer()

@@ -55,8 +55,10 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from ..core.comm import CommLog
+from ..obs.tracing import trace_span
 
 # A round body: (k, state, data) -> state.  ``k`` is the (traced) round
 # index, ``state`` a dict whose entries are arrays or small pytrees of
@@ -74,6 +76,55 @@ from ..core.comm import CommLog
 # touches (the Gram-cached fast paths never re-read the raw designs).
 RoundBody = Callable[[jnp.ndarray, Dict[str, jnp.ndarray],
                       Dict[str, jnp.ndarray]], Dict[str, jnp.ndarray]]
+
+
+def stage(jitted, args: tuple):
+    """Stage ``jitted`` for ``args`` in JAX's ahead-of-time steps and
+    return the compiled program: the work ``jit`` does on a first call,
+    each step under its own span (``solve.trace`` — the round body runs
+    abstractly here, so the primitives record their ledger template;
+    ``solve.lower``; ``solve.compile`` — an XLA compile, or the
+    persistent cache's key, read and deserialize)."""
+    with trace_span("solve.trace"):
+        traced = jitted.trace(*args)
+    with trace_span("solve.lower"):
+        lowered = traced.lower()
+    with trace_span("solve.compile"):
+        return lowered.compile()
+
+
+class _Staged:
+    """A jitted round program that :func:`stage` compiles on its first
+    concrete call; every call then dispatches under ``solve.run``."""
+
+    def __init__(self, jitted):
+        self.jitted, self.compiled = jitted, None
+
+    def __call__(self, *args):
+        if any(isinstance(x, jax.core.Tracer) for x in jax.tree.leaves(args)):
+            return self.jitted(*args)            # inside make_jaxpr
+        if self.compiled is None:
+            self.compiled = stage(self.jitted, args)
+        with trace_span("solve.run"):
+            return self.compiled(*args)
+
+
+def round_jit(fn, **jit_kwargs):
+    """``jax.jit(fn, **jit_kwargs)`` for a round program.  While a
+    profiler trace records, the program is staged in spans instead
+    (:class:`_Staged`); otherwise it is the plain jitted function.
+
+    Staging does the work of a first ``jit`` call, but from other Python
+    frames, and lowering time depends on the frames below it: one
+    50-round program lowered in 0.57 s or 1.0 s on a TPU v5e host with
+    nothing changed but the caller's stack depth (likely CPython 3.12's
+    frame-stack chunks, mapped and unmapped as a hot call crosses a
+    chunk's end).  With tracing off the program keeps ``jit``'s call
+    path, frame for frame, so the spans move nothing a benchmark
+    times."""
+    jitted = jax.jit(fn, **jit_kwargs)
+    return _Staged(jitted) if TraceAnnotation.is_enabled() else jitted
+
 
 # Worker-data leaves whose axis 1 is the per-task SAMPLE axis — these
 # are the leaves a 2-D runtime shards along the "data" mesh axis.
@@ -224,9 +275,15 @@ class ProtocolRuntime:
         sample statistics computed from them must be reduced with
         :meth:`pmean_data` / :meth:`psum_data` afterwards (the
         ``repro.core.worker_ops`` helpers do this when handed the
-        runtime).
+        runtime).  The mapped call runs under the ``worker`` named
+        scope, which the lowered program's op names carry.
         """
-        return jax.vmap(fn, in_axes=in_axes, out_axes=out_axes)
+        mapped = jax.vmap(fn, in_axes=in_axes, out_axes=out_axes)
+
+        def call(*args, **kwargs):
+            with jax.named_scope("worker"):
+                return mapped(*args, **kwargs)
+        return call
 
     def axis_index(self) -> jnp.ndarray:
         """Index of this worker view along the task axis (0 under sim)."""
@@ -345,11 +402,12 @@ class ProtocolRuntime:
         executes it more than once per round (e.g. a Newton refit loop)
         so the measurement stays honest despite single-trace recording.
         """
-        if self.data_shards == 1:
-            return self._norm_collective(x)
-        if self._count_data_wire:
-            self._charge_data("psum", x.size, repeats, note)
-        return self._norm_collective(self._psum_data(x))
+        with jax.named_scope("exchange"):
+            if self.data_shards == 1:
+                return self._norm_collective(x)
+            if self._count_data_wire:
+                self._charge_data("psum", x.size, repeats, note)
+            return self._norm_collective(self._psum_data(x))
 
     def pmean_data(self, x: jnp.ndarray, note: str = "",
                    repeats: int = 1) -> jnp.ndarray:
@@ -361,11 +419,12 @@ class ProtocolRuntime:
         when ``data_shards == 1``; accounting and aval normalization as
         :meth:`psum_data`.
         """
-        if self.data_shards == 1:
-            return self._norm_collective(x)
-        if self._count_data_wire:
-            self._charge_data("psum", x.size, repeats, note)
-        return self._norm_collective(self._pmean_data(x))
+        with jax.named_scope("exchange"):
+            if self.data_shards == 1:
+                return self._norm_collective(x)
+            if self._count_data_wire:
+                self._charge_data("psum", x.size, repeats, note)
+            return self._norm_collective(self._pmean_data(x))
 
     def gather_samples(self, x: jnp.ndarray, axis: int = 1,
                        note: str = "") -> jnp.ndarray:
@@ -383,7 +442,8 @@ class ProtocolRuntime:
             return x
         if self._count_data_wire:
             self._charge_data("all_gather", x.size, 1, note)
-        return self._gather_samples(x, axis)
+        with jax.named_scope("exchange"):
+            return self._gather_samples(x, axis)
 
     # Whether this backend moves real bytes over the data axis (mesh
     # collectives: yes; the sim emulation: no, mirroring the tasks-axis

@@ -39,7 +39,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
-from .base import SAMPLE_AXIS_LEAVES, ProtocolRuntime
+from .base import SAMPLE_AXIS_LEAVES, ProtocolRuntime, round_jit
 
 # Every shard_map here passes check_vma=False: replicated-master state
 # is identical on all chips by construction (deterministic ops on
@@ -152,13 +152,16 @@ class MeshRuntime(ProtocolRuntime):
         # x: (d, L) local columns -> (d, m); each machine ships 1 d-vector.
         self._charge("worker->master", 1, x.shape[0], note, wire=x.size,
                      kind="all_gather", payload=x.size)
-        return jax.lax.all_gather(x, self.axis, axis=x.ndim - 1, tiled=True)
+        with jax.named_scope("exchange"):
+            return jax.lax.all_gather(x, self.axis, axis=x.ndim - 1,
+                                      tiled=True)
 
     def gather_tasks(self, x, note: str = ""):
         vectors, dim = self._payload_vectors(x)
         self._charge("worker->master", vectors, dim, note, wire=x.size,
                      kind="all_gather", payload=x.size)
-        return jax.lax.all_gather(x, self.axis, axis=0, tiled=True)
+        with jax.named_scope("exchange"):
+            return jax.lax.all_gather(x, self.axis, axis=0, tiled=True)
 
     def sum_tasks(self, x, note: str = ""):
         vectors, dim = self._payload_vectors(x)
@@ -167,7 +170,8 @@ class MeshRuntime(ProtocolRuntime):
         # times smaller — the analyzer matches the latter in the jaxpr
         self._charge("worker->master", vectors, dim, note, wire=x.size,
                      kind="psum", payload=x.size // x.shape[0])
-        return jax.lax.psum(jnp.sum(x, axis=0), self.axis)
+        with jax.named_scope("exchange"):
+            return jax.lax.psum(jnp.sum(x, axis=0), self.axis)
 
     # -- data axis: real collectives over the mesh's "data" axis -------
     _count_data_wire = True
@@ -291,7 +295,7 @@ class MeshRuntime(ProtocolRuntime):
                        in_specs=(P(), state_specs, data_specs),
                        out_specs=state_specs,
                        check_vma=False)
-        step = jax.jit(fn)
+        step = round_jit(fn)
         return lambda t, s: step(self._bind_scalar(t),
                                  self._bind_state(s, sharded), data)
 
@@ -315,7 +319,7 @@ class MeshRuntime(ProtocolRuntime):
                        out_specs=(state_specs, snaps_spec),
                        check_vma=False)
         donate = self._state_donation()
-        step = jax.jit(fn, donate_argnums=donate)
+        step = round_jit(fn, donate_argnums=donate)
         return lambda s: step(
             self._shield_donated(self._bind_state(s, sharded), donate),
             data)
@@ -336,7 +340,7 @@ class MeshRuntime(ProtocolRuntime):
                        out_specs=(state_specs, snaps_spec),
                        check_vma=False)
         donate = self._state_donation()
-        step = jax.jit(fn, donate_argnums=donate)
+        step = round_jit(fn, donate_argnums=donate)
         return lambda s, start, slots: step(
             self._shield_donated(self._bind_state(s, sharded), donate),
             data, self._bind_scalar(start),

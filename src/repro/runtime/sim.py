@@ -21,7 +21,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .base import SAMPLE_AXIS_LEAVES, ProtocolRuntime
+from .base import SAMPLE_AXIS_LEAVES, ProtocolRuntime, round_jit
 
 
 class SimRuntime(ProtocolRuntime):
@@ -58,7 +58,8 @@ class SimRuntime(ProtocolRuntime):
     def sum_tasks(self, x, note: str = ""):
         vectors, dim = self._payload_vectors(x)
         self._charge("worker->master", vectors, dim, note, wire=0)
-        return jnp.sum(x, axis=0)
+        with jax.named_scope("exchange"):
+            return jnp.sum(x, axis=0)
 
     # -- data axis: lax collectives over the emulation's vmapped axis --
     def _psum_data(self, x):
@@ -120,13 +121,13 @@ class SimRuntime(ProtocolRuntime):
         # does not constant-fold per-task Gram matrices at compile time.
         data = self._round_data()
         if self.data_shards == 1:
-            @jax.jit
+            @round_jit
             def step(k, state, data):
                 return body(k, state, data)
         else:
             axes = self._data_in_axes(data)
 
-            @jax.jit
+            @round_jit
             def step(k, state, data):
                 # axis_size keeps the emulated data axis alive even
                 # when pruning left no sample leaves to map over
@@ -144,7 +145,7 @@ class SimRuntime(ProtocolRuntime):
         data = self._round_data()
         if self.data_shards == 1:
             donate = self._state_donation()
-            step = jax.jit(program, donate_argnums=donate)
+            step = round_jit(program, donate_argnums=donate)
             return lambda s: step(self._shield_donated(s, donate), data)
 
         axes = self._data_in_axes(data)
@@ -153,7 +154,7 @@ class SimRuntime(ProtocolRuntime):
                          axis_size=self.data_shards)
         # no donation: the emulated program's outputs are (D, ...)
         # batched, so the (global-shaped) input buffers cannot be reused
-        step = jax.jit(lambda s, d: self._unreplicate(vprog(s, d)))
+        step = round_jit(lambda s, d: self._unreplicate(vprog(s, d)))
         return lambda s: step(s, data)
 
     def _compile_segment(self, body, state, sharded, seg_len, seg_records):
@@ -161,7 +162,7 @@ class SimRuntime(ProtocolRuntime):
         data = self._round_data()
         if self.data_shards == 1:
             donate = self._state_donation()
-            step = jax.jit(program, donate_argnums=donate)
+            step = round_jit(program, donate_argnums=donate)
             return lambda s, start, slots: step(
                 self._shield_donated(s, donate), data,
                 jnp.int32(start), jnp.asarray(slots, jnp.int32))
@@ -170,7 +171,7 @@ class SimRuntime(ProtocolRuntime):
         vprog = jax.vmap(program, in_axes=(None, axes, None, None),
                          out_axes=0, axis_name=self.data_axis,
                          axis_size=self.data_shards)
-        step = jax.jit(lambda s, d, k0, sl: self._unreplicate(
+        step = round_jit(lambda s, d, k0, sl: self._unreplicate(
             vprog(s, d, k0, sl)))
         return lambda s, start, slots: step(
             s, data, jnp.int32(start), jnp.asarray(slots, jnp.int32))

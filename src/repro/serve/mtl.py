@@ -52,7 +52,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.losses import get_loss
 from ..obs.metrics import default_registry
-from ..obs.tracing import emit_event, trace_span
+from ..obs.tracing import annotate, emit_event, trace_span
 from ..train import checkpoint
 
 _MANIFEST_VERSION = 1
@@ -611,46 +611,62 @@ class MTLServer:
         return _score_batch(st.U, st.C, wid, wX, st.model.m)
 
     def _score_with(self, st: _ServeState, task_ids, X) -> jnp.ndarray:
-        """Score a batch against ONE state snapshot (hot-swap safe)."""
-        ids = jnp.asarray(task_ids, jnp.int32)
-        X = jnp.asarray(X)
-        if ids.ndim != 1 or X.ndim != 2 or X.shape[0] != ids.shape[0]:
-            raise ValueError(f"want ids (N,) and X (N, p); got "
-                             f"{ids.shape} and {X.shape}")
-        if X.shape[1] != st.model.p:
-            raise ValueError(f"feature dim {X.shape[1]} != model p "
-                             f"{st.model.p}")
-        n, B = ids.shape[0], self.B
-        if n == 0:
-            return jnp.zeros((0,), X.dtype)
-        # SLO latency window: the jit'd dispatch loop + the one host
-        # validity sync — perf_counter (monotonic, high-res) measured
-        # on the host AROUND the device work, not inside it
-        t0 = time.perf_counter()
-        outs: List[jnp.ndarray] = []
-        oks: List[jnp.ndarray] = []
-        one_wave = n == B                      # the common serving case:
-        for lo in range(0, n, B):              # no slicing, no reassembly
-            wid = ids if one_wave else ids[lo:lo + B]
-            wX = X if one_wave else X[lo:lo + B]
-            fill = B - wid.shape[0]
-            if fill:                           # pad the last wave
-                wid = jnp.concatenate([wid, jnp.zeros((fill,), wid.dtype)])
-                wX = jnp.concatenate(
-                    [wX, jnp.zeros((fill, wX.shape[1]), wX.dtype)])
-            preds, ok = self._score_dispatch(st, wid, wX)
-            outs.append(preds[:B - fill] if fill else preds)
-            oks.append(ok)
-        # ONE host round-trip validates every wave of the call
-        ok_all = oks[0] if len(oks) == 1 else jnp.all(jnp.stack(oks))
-        if not bool(ok_all):
-            self._bad.inc()
-            raise ValueError(f"task ids outside [0, {st.model.m}) in "
-                             "this model version")
-        self._lat.observe(time.perf_counter() - t0)
-        self._req.inc(n)
-        self._wav.inc(len(outs))
-        return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+        """Score a batch against ONE state snapshot (hot-swap safe).
+
+        Each step runs under a profiler-only span (``repro.obs.annotate``):
+        ``serve.score`` around the call, holding ``serve.prepare`` (the
+        conversions and shape checks), ``serve.pad`` (the last wave's
+        padding), one ``serve.dispatch`` per wave and ``serve.sync`` (the
+        validity flag's read-back, which waits for the device)."""
+        B = self.B
+        with annotate("serve.score"):
+            with annotate("serve.prepare"):
+                ids = jnp.asarray(task_ids, jnp.int32)
+                X = jnp.asarray(X)
+                if (ids.ndim != 1 or X.ndim != 2
+                        or X.shape[0] != ids.shape[0]):
+                    raise ValueError(f"want ids (N,) and X (N, p); got "
+                                     f"{ids.shape} and {X.shape}")
+                if X.shape[1] != st.model.p:
+                    raise ValueError(f"feature dim {X.shape[1]} != model "
+                                     f"p {st.model.p}")
+            n = ids.shape[0]
+            if n == 0:
+                return jnp.zeros((0,), X.dtype)
+            # SLO latency window: the jit'd dispatch loop + the one host
+            # validity sync — perf_counter (monotonic, high-res) measured
+            # on the host AROUND the device work, not inside it
+            t0 = time.perf_counter()
+            outs: List[jnp.ndarray] = []
+            oks: List[jnp.ndarray] = []
+            one_wave = n == B                  # the common serving case:
+            for lo in range(0, n, B):          # no slicing, no reassembly
+                wid = ids if one_wave else ids[lo:lo + B]
+                wX = X if one_wave else X[lo:lo + B]
+                fill = B - wid.shape[0]
+                if fill:                       # pad the last wave
+                    with annotate("serve.pad"):
+                        wid = jnp.concatenate(
+                            [wid, jnp.zeros((fill,), wid.dtype)])
+                        wX = jnp.concatenate(
+                            [wX, jnp.zeros((fill, wX.shape[1]), wX.dtype)])
+                with annotate("serve.dispatch"):
+                    preds, ok = self._score_dispatch(st, wid, wX)
+                outs.append(preds[:B - fill] if fill else preds)
+                oks.append(ok)
+            # ONE host round-trip validates every wave of the call
+            with annotate("serve.sync"):
+                ok_all = oks[0] if len(oks) == 1 else \
+                    jnp.all(jnp.stack(oks))
+                valid = bool(ok_all)
+            if not valid:
+                self._bad.inc()
+                raise ValueError(f"task ids outside [0, {st.model.m}) in "
+                                 "this model version")
+            self._lat.observe(time.perf_counter() - t0)
+            self._req.inc(n)
+            self._wav.inc(len(outs))
+            return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
 
     def score(self, task_ids, X) -> Tuple[jnp.ndarray, str]:
         """Score a mixed-task request batch: (N,) margins + the version
