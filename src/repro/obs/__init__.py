@@ -7,11 +7,12 @@ Three pillars (DESIGN.md §15):
   carry into ``MTLResult.extras["metrics"]``; metrics off ⇒
   bit-identical solves, metrics on ⇒ bit-identical W + ledger;
 * host span tracing — :func:`trace_span` / :func:`emit_event` around
-  solve calls (and, under a profiler trace, their staging), checkpoint
+  solve calls and their round programs' staging, checkpoint
   saves, resume/rollback, streaming refresh phases, and server
   install/hot-swap/onboard, exportable as JSONL and Chrome
   ``trace.json`` and present on a ``jax.profiler`` trace, plus
-  profiler-only :func:`annotate` spans around each scoring call
+  profiler-only :func:`annotate` spans around each scoring call and
+  each round-program dispatch
   (:mod:`~repro.obs.tracing`);
 * serving SLO metrics — latency histograms / counters / staleness
   gauges in a shared :class:`MetricsRegistry` with JSONL + Prometheus

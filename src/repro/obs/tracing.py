@@ -11,15 +11,17 @@ One tracer, two tiers, one clock.
   float and bool attrs), so under a ``jax.profiler`` trace it lands on
   the profiler's host plane, on the same clock as the device's
   ``XLA Ops`` events.  These mark the rare, expensive phases: ``solve``
-  and, while a profiler trace records, its ``solve.trace`` /
-  ``solve.lower`` / ``solve.compile`` / ``solve.run`` stages
+  and its round program's ``solve.trace`` (the ring event's ``hit``
+  says whether the process had the program compiled already) and, on a
+  miss, ``solve.lower`` / ``solve.compile``
   (``repro.runtime.base.round_jit``), checkpoint saves, server install
   / hot-swap / onboard, streaming refreshes.
 * Per-call spans (profiler only) — ``with annotate("serve.score"):
   ...`` is a ``TraceAnnotation`` and nothing else: no ring
   append, no lock, no clock read.  It exists for boundaries crossed
   hundreds of times a second (each ``MTLServer.score`` call and its
-  prepare / pad / dispatch / sync steps): in the ring they would evict
+  prepare / pad / dispatch / sync steps, each round program's dispatch
+  under ``solve.run``): in the ring they would evict
   every lifecycle event within seconds and pay a locked append on
   every call.  With no profiler trace running, entering and leaving
   one costs about a microsecond.

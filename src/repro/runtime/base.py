@@ -51,14 +51,21 @@ measured separately into ``data_collective_floats_per_chip``.
 from __future__ import annotations
 
 import dataclasses
+import hashlib
+import re
+import threading
+from collections import OrderedDict, namedtuple
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.profiler import TraceAnnotation
+import numpy as np
+from jax._src import config as jax_config
+from jax._src import dispatch as jax_dispatch
+from jax.extend import core as jcore
 
 from ..core.comm import CommLog
-from ..obs.tracing import trace_span
+from ..obs.tracing import annotate, trace_span
 
 # A round body: (k, state, data) -> state.  ``k`` is the (traced) round
 # index, ``state`` a dict whose entries are arrays or small pytrees of
@@ -78,52 +85,219 @@ RoundBody = Callable[[jnp.ndarray, Dict[str, jnp.ndarray],
                       Dict[str, jnp.ndarray]], Dict[str, jnp.ndarray]]
 
 
-def stage(jitted, args: tuple):
-    """Stage ``jitted`` for ``args`` in JAX's ahead-of-time steps and
-    return the compiled program: the work ``jit`` does on a first call,
-    each step under its own span (``solve.trace`` — the round body runs
-    abstractly here, so the primitives record their ledger template;
-    ``solve.lower``; ``solve.compile`` — an XLA compile, or the
-    persistent cache's key, read and deserialize)."""
-    with trace_span("solve.trace"):
-        traced = jitted.trace(*args)
+def stage(traced):
+    """Lower and compile a traced round program, each step under its
+    own span (``solve.lower``; ``solve.compile`` — an XLA compile, or
+    the persistent cache's key, read and deserialize).  The work of a
+    first ``jit`` call after its trace, done once per process per
+    traced program (:func:`round_jit`)."""
     with trace_span("solve.lower"):
         lowered = traced.lower()
     with trace_span("solve.compile"):
         return lowered.compile()
 
 
-class _Staged:
-    """A jitted round program that :func:`stage` compiles on its first
-    concrete call; every call then dispatches under ``solve.run``."""
+# The compiled round programs of this process, least recently used
+# first, keyed on the traced program (:func:`_program_key`).  Only
+# ``Compiled`` objects live here: never a jitted closure or a ``Traced``,
+# which would pin the problem that closure captured.
+_PROGRAMS: "OrderedDict[tuple, jax.stages.Compiled]" = OrderedDict()
+_PROGRAMS_MAX = 8
+_programs_lock = threading.Lock()
+_counts = {"hits": 0, "misses": 0}
 
-    def __init__(self, jitted):
-        self.jitted, self.compiled = jitted, None
+CacheInfo = namedtuple("CacheInfo", "hits misses maxsize currsize")
+
+# ``<function f at 0x7f...>``: an object named by its address, which a
+# later object can reuse once this one is freed
+_ADDRESS = re.compile(r" at 0x[0-9a-fA-F]+")
+
+
+def cache_info() -> CacheInfo:
+    """Hits and misses of the round-program cache since the last
+    :func:`cache_clear`, as ``functools.lru_cache`` reports its own."""
+    with _programs_lock:
+        return CacheInfo(_counts["hits"], _counts["misses"], _PROGRAMS_MAX,
+                         len(_PROGRAMS))
+
+
+def cache_clear() -> None:
+    """Drop every cached round program and zero the counters."""
+    with _programs_lock:
+        _PROGRAMS.clear()
+        _counts.update(hits=0, misses=0)
+
+
+def _arg_signature(leaves, tree) -> tuple:
+    """What a compiled program fixes of its arguments: the pytree, and
+    each leaf's aval (shape, dtype, weak type), sharding (its devices
+    included) and whether it is committed to them."""
+    return tree, tuple(
+        (jax.typeof(x), x.sharding, x.committed) if isinstance(x, jax.Array)
+        else (jax.typeof(x), None, None) for x in leaves)
+
+
+def _value(x) -> str:
+    """A constant's or literal's dtype, shape and bytes."""
+    x = np.asarray(x)
+    return f"{x.dtype}{x.shape}{np.ascontiguousarray(x).tobytes().hex()}"
+
+
+def _program_text(closed) -> Optional[str]:
+    """The closed jaxpr written out whole, or None when it names an
+    object by its address (a host callback): the address of a freed
+    object can be reused.
+
+    What ``str(closed)`` prints — every equation, the avals of its
+    binders, each parameter's ``str`` (literals such as a solver's
+    ``lam`` or step included, the Pallas calls' kernel bodies, grids and
+    block shapes) — and what it leaves out: the values of constants and
+    literals at every depth (a nested ``jit`` keeps the constants it
+    closed over in its own jaxpr, which ``str`` prints as bare
+    variables), and each equation's context.  About half the time of
+    ``str``, which lays the text out for reading."""
+    out, contexts = [], {}
+
+    def param(p):
+        if isinstance(p, jcore.ClosedJaxpr):
+            out.extend(_value(c) for c in p.consts)
+            jaxpr(p.jaxpr)
+        elif isinstance(p, jcore.Jaxpr):
+            jaxpr(p)
+        elif isinstance(p, (tuple, list)) and any(
+                isinstance(q, (jcore.Jaxpr, jcore.ClosedJaxpr)) for q in p):
+            out.append("(")
+            for q in p:
+                param(q)
+            out.append(")")
+        else:
+            out.append(str(p))
+
+    def jaxpr(j):
+        names = {}
+
+        def bind(vs):
+            for v in vs:
+                names[v] = len(names)
+                out.append(str(v.aval))
+
+        def use(vs):
+            out.extend(_value(v.val) if isinstance(v, jcore.Literal)
+                       else str(names[v]) for v in vs)
+
+        out.append("{")
+        bind(j.constvars)
+        out.append(";")
+        bind(j.invars)
+        for e in j.eqns:
+            out.append(e.primitive.name)
+            use(e.invars)
+            if id(e.ctx) not in contexts:
+                contexts[id(e.ctx)] = repr(e.ctx)
+            out.append(contexts[id(e.ctx)])
+            for k, p in e.params.items():
+                out.append(k)
+                param(p)
+            out.append("->")
+            bind(e.outvars)
+        out.append("in")
+        use(j.outvars)
+        out.append(f"{j.effects}}}")
+
+    param(closed)
+    text = "\n".join(out)
+    return None if _ADDRESS.search(text) else text
+
+
+def _program_key(traced, signature, jit_kwargs) -> Optional[tuple]:
+    """The cache key of a traced round program, or None when it has none
+    (:func:`_program_text`).
+
+    Everything that decides the executable: the program written out
+    whole (:func:`_program_text`), the shardings inside it (a
+    ``shard_map``'s mesh prints without its devices: a mesh of the same
+    shape over other chips prints the same text), the output pytree, the
+    arguments' signature (:func:`_arg_signature`), the ``jax.jit``
+    options (``donate_argnums``), the backend, and JAX's trace-context
+    configuration (``default_matmul_precision`` and every other setting
+    that decides how a program traces and lowers)."""
+    text = _program_text(traced.jaxpr)
+    if text is None:
+        return None
+    inside = tuple(dict.fromkeys(
+        sh for sh, _ in jax_dispatch.get_intermediate_shardings(
+            traced.jaxpr.jaxpr)))
+    return (hashlib.sha256(text.encode()).hexdigest(), inside,
+            traced.out_tree, signature, tuple(sorted(jit_kwargs.items())),
+            jax.default_backend(), jax_config.trace_context())
+
+
+def _lookup(jitted, args, signature, jit_kwargs):
+    """The compiled program for ``args``: traced under ``solve.trace``
+    (the round body runs abstractly here, so the primitives record their
+    ledger template on every call), then taken from the process's cache
+    (the span's ``hit``) or staged and stored."""
+    with trace_span("solve.trace") as span:
+        traced = jitted.trace(*args)
+        key = _program_key(traced, signature, jit_kwargs)
+        with _programs_lock:
+            compiled = None if key is None else _PROGRAMS.get(key)
+            if compiled is None:
+                _counts["misses"] += 1
+            else:
+                _counts["hits"] += 1
+                _PROGRAMS.move_to_end(key)
+        span["hit"] = compiled is not None
+    if compiled is None:
+        compiled = stage(traced)
+        if key is not None:
+            with _programs_lock:
+                _PROGRAMS[key] = compiled
+                while len(_PROGRAMS) > _PROGRAMS_MAX:
+                    _PROGRAMS.popitem(last=False)
+    return compiled
+
+
+class _Staged:
+    """A round program that finds its compiled program on each new
+    argument signature (:func:`_lookup`), keeps it for the object's
+    later calls (the eager driver's rounds, the segment driver's
+    segments) and dispatches under ``solve.run``."""
+
+    def __init__(self, jitted, jit_kwargs):
+        self.jitted, self.jit_kwargs = jitted, jit_kwargs
+        self.compiled = {}
 
     def __call__(self, *args):
-        if any(isinstance(x, jax.core.Tracer) for x in jax.tree.leaves(args)):
+        leaves, tree = jax.tree.flatten(args)
+        if any(isinstance(x, jax.core.Tracer) for x in leaves):
             return self.jitted(*args)            # inside make_jaxpr
-        if self.compiled is None:
-            self.compiled = stage(self.jitted, args)
-        with trace_span("solve.run"):
-            return self.compiled(*args)
+        signature = _arg_signature(leaves, tree)
+        compiled = self.compiled.get(signature)
+        if compiled is None:
+            compiled = self.compiled[signature] = _lookup(
+                self.jitted, args, signature, self.jit_kwargs)
+        with annotate("solve.run"):
+            return compiled(*args)
 
 
 def round_jit(fn, **jit_kwargs):
-    """``jax.jit(fn, **jit_kwargs)`` for a round program.  While a
-    profiler trace records, the program is staged in spans instead
-    (:class:`_Staged`); otherwise it is the plain jitted function.
+    """``jax.jit(fn, **jit_kwargs)`` for a round program, staged once
+    per process per traced program.
 
-    Staging does the work of a first ``jit`` call, but from other Python
-    frames, and lowering time depends on the frames below it: one
-    50-round program lowered in 0.57 s or 1.0 s on a TPU v5e host with
-    nothing changed but the caller's stack depth (likely CPython 3.12's
-    frame-stack chunks, mapped and unmapped as a hot call crosses a
-    chunk's end).  With tracing off the program keeps ``jit``'s call
-    path, frame for frame, so the spans move nothing a benchmark
-    times."""
-    jitted = jax.jit(fn, **jit_kwargs)
-    return _Staged(jitted) if TraceAnnotation.is_enabled() else jitted
+    Every solve builds fresh closures over its runtime, problem and
+    hyper-parameters, so ``jax.jit``'s own caches, keyed on the function
+    object, never see a program twice.  Here each new argument signature
+    traces the program (tens of milliseconds, and where the ledger
+    template is recorded) and keys a process-level cache on what was
+    traced (:func:`_program_key`): a later solve that traces to the same
+    program — the same solver, hyper-parameters and shapes on other data
+    — reuses its compiled program and skips lowering and compiling.  A
+    literal baked into the program, such as ``lam``, a data-derived step
+    or a stochastic solve's ``batch_seed``, is part of the key, so
+    changing it stages anew.  :func:`cache_info` counts hits and misses;
+    the cache holds the last eight programs."""
+    return _Staged(jax.jit(fn, **jit_kwargs), jit_kwargs)
 
 
 # Worker-data leaves whose axis 1 is the per-task SAMPLE axis — these

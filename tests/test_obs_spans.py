@@ -3,10 +3,11 @@
 Each test runs its program under a ``jax.profiler`` trace and reads the
 host events back with ``jax.profiler.ProfileData``:
 
-* under a trace, a solve stages its round program in four spans —
-  ``solve.trace``, ``solve.lower``, ``solve.compile``, ``solve.run`` —
-  nested in ``solve``, and tracing changes nothing the solve returns;
-  with no trace the program keeps the plain ``jit`` call path;
+* a solve traces its round program under ``solve.trace`` and runs it
+  under ``solve.run``, nested in ``solve``; only the first solve of a
+  program in the process lowers and compiles it (``solve.lower``,
+  ``solve.compile``), with a profiler trace or without one, and tracing
+  changes nothing the solve returns;
 * a ``score`` call opens ``serve.score`` holding ``serve.prepare``, a
   ``serve.pad`` only when the last wave is padded, one ``serve.dispatch``
   a wave and one ``serve.sync``; those per-call spans never enter the
@@ -34,6 +35,14 @@ jax.config.update("jax_platform_name", "cpu")
 SPEC = SimSpec(p=10, m=8, r=2, n=24)
 STAGES = ("solve.trace", "solve.lower", "solve.compile", "solve.run")
 B = 4
+
+
+@pytest.fixture
+def cleared():
+    """An empty round-program cache, so the test's first solve stages."""
+    base.cache_clear()
+    yield
+    base.cache_clear()
 
 
 @pytest.fixture(scope="module")
@@ -89,17 +98,25 @@ def _solve(prob, backend):
 
 
 @pytest.mark.parametrize("backend", ["sim", "mesh"])
-def test_solve_stages_once_each_inside_solve(prob, profiled, backend):
+def test_solve_stages_once_each_inside_solve(prob, profiled, cleared,
+                                             backend):
     with profiled() as events:
         _solve(prob, backend)
-    (solve,) = _named(events, "solve")
+        _solve(prob, backend)
+    first, again = sorted(_named(events, "solve"), key=lambda e: e[1])
     for name in STAGES:
         spans = _named(events, name)
-        assert len(spans) == 1, (name, spans)
-        assert _inside(spans[0], solve), name
+        assert _inside(spans[0], first), name
+        # the identical second solve traces and runs, and reuses the
+        # compiled program
+        assert len(spans) == (2 if name in ("solve.trace", "solve.run")
+                              else 1), (name, spans)
     # the stages run in order, one after another
     starts = [_named(events, n)[0][1] for n in STAGES]
     assert starts == sorted(starts)
+    for name in ("solve.trace", "solve.run"):
+        assert _inside(max(_named(events, name), key=lambda e: e[1]),
+                       again), name
 
 
 @pytest.mark.parametrize("backend", ["sim", "mesh"])
@@ -112,7 +129,7 @@ def test_trace_changes_nothing_the_solve_returns(prob, profiled, backend):
     assert bare.comm.summary() == traced.comm.summary()
 
 
-def test_eager_driver_stages_once(prob, profiled):
+def test_eager_driver_stages_once(prob, profiled, cleared):
     with profiled() as events:
         res = repro.solve(prob, method="proxgd", rounds=3, lam=0.05,
                           scan=False)
@@ -123,16 +140,22 @@ def test_eager_driver_stages_once(prob, profiled):
 
 
 @pytest.mark.parametrize("backend", ["sim", "mesh"])
-def test_untraced_solve_keeps_the_jit_call_path(prob, monkeypatch, backend):
+def test_untraced_solve_keeps_the_jit_call_path(prob, monkeypatch, cleared,
+                                                backend):
+    # with no profiler trace a solve takes the same path as under one:
+    # two identical solves stage their program once
     staged = []
+    stage = base.stage
     monkeypatch.setattr(base, "stage",
-                        lambda *a: staged.append(a) or pytest.fail("staged"))
+                        lambda traced: staged.append(traced) or stage(traced))
     ring = default_tracer()
     before = len(ring.events())
     _solve(prob, backend)
-    assert staged == []
-    names = {e["name"] for e in ring.events()[before:]}
-    assert "solve" in names and not names & set(STAGES)
+    _solve(prob, backend)
+    assert len(staged) == 1
+    names = [e["name"] for e in ring.events()[before:]]
+    assert names.count("solve") == 2 and names.count("solve.trace") == 2
+    assert names.count("solve.lower") == names.count("solve.compile") == 1
 
 
 @pytest.fixture(scope="module")
@@ -185,13 +208,13 @@ def test_score_calls_leave_the_ring_alone(server):
 
 
 def test_lowered_round_program_names_worker_and_shrink(prob, monkeypatch,
-                                                       profiled):
+                                                       profiled, cleared):
     texts = []
     stage = base.stage
 
-    def keep_text(jitted, args):
-        texts.append(jitted.trace(*args).lower().as_text(debug_info=True))
-        return stage(jitted, args)
+    def keep_text(traced):
+        texts.append(traced.lower().as_text(debug_info=True))
+        return stage(traced)
 
     monkeypatch.setattr(base, "stage", keep_text)
     with profiled():
