@@ -76,9 +76,11 @@ SCORE_REL_TOL = 1e-2
 # in summation order (the data axis splits each task's rows).
 MESH_W_REL_TOL = 1e-3
 MESH_SCORE_REL_TOL = 1e-4
-# The Gram path against the raw path on four chips: the cached Gram
-# matvecs take the chip's default f32 matmul, the raw kernel full f32.
-GRAM_W_REL_TOL = 2e-2
+# The Gram path against the raw path on four chips: both compute in full
+# float32 (the cache built at matmul precision 'highest' and read by a
+# float32 multiply-and-reduce, the raw kernel on the vector unit), so
+# they differ in summation order only, as the mesh layouts do.
+GRAM_W_REL_TOL = 1e-3
 # Onboarding a task drawn from the learned subspace recovers its code.
 ONBOARD_CODE_TOL = 5e-2
 

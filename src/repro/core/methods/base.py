@@ -11,8 +11,10 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 
+from ...obs.tracing import trace_span
 from ..comm import CommLog
 from ..losses import Loss, get_loss
 
@@ -92,7 +94,15 @@ class MTLProblem:
         prob = cls(Xs=Xs, ys=ys, loss=loss, **kw)
         if gram and loss.name == "squared":
             from ..worker_ops import gram_stats
-            prob.gram_A, prob.gram_b = gram_stats(Xs, ys)
+            # the cache lies where Xs does: split along the task axis
+            # over the chips of a tasks-sharded Xs
+            m, _, p = Xs.shape
+            rows = Xs.sharding.shard_shape(Xs.shape)[0]
+            per_chip = rows * (p * p + p) * Xs.dtype.itemsize
+            with trace_span("problem.gram", bytes_per_chip=per_chip,
+                            shards=m // rows):
+                prob.gram_A, prob.gram_b = gram_stats(Xs, ys)
+                jax.block_until_ready(prob.gram_b)
         return prob
 
 

@@ -26,6 +26,8 @@ communication either way (DESIGN.md §9).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -50,12 +52,14 @@ def data_smoothness(prob: MTLProblem) -> float:
     no pass over the raw (n, p) designs.
     """
     def spec(C):
+        mv = functools.partial(jnp.matmul, C,
+                               precision=worker_ops.GRAM_PRECISION)
         v = jnp.ones((C.shape[0],), C.dtype) / jnp.sqrt(C.shape[0])
         def body(_, v):
-            w = C @ v
+            w = mv(v)
             return w / jnp.maximum(jnp.linalg.norm(w), 1e-30)
         v = jax.lax.fori_loop(0, 50, body, v)
-        return v @ (C @ v)
+        return v @ mv(v)
 
     if prob.gram_A is not None:
         lmax = jnp.max(jax.vmap(spec)(prob.gram_A))

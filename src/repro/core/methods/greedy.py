@@ -206,10 +206,13 @@ def altmin(prob: MTLProblem, rank: int = None, rounds: int = 30,
             # min_U (1/2nm) sum_j ||X_j U v_j - y_j||^2: vec(U) solve from
             # per-task moments, summed on the master.
             if worker_ops.has_gram(data):
+                # elementwise products: float32 at any matmul precision
                 def moments(A, b, v):
                     return jnp.kron(jnp.outer(v, v), A), jnp.kron(v, b)
-                A_all, b_all = rt.worker_map(moments, in_axes=(0, 0, 1))(
-                    data["gram_A"], data["gram_b"], V)
+                with jax.named_scope("gram_grad"):
+                    A_all, b_all = rt.worker_map(
+                        moments, in_axes=(0, 0, 1))(
+                        data["gram_A"], data["gram_b"], V)
             else:
                 # per-task second moments from the local rows; the /n
                 # uses the GLOBAL sample count, so the data-axis psum

@@ -39,6 +39,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
+from ..core.worker_ops import GRAM_PRECISION
 from .base import SAMPLE_AXIS_LEAVES, ProtocolRuntime, round_jit
 
 # Every shard_map here passes check_vma=False: replicated-master state
@@ -88,8 +89,9 @@ def _shard_gram_fn(mesh: Mesh, axis: str, data_axis: str):
 
     def program(Xs, ys):                # (L, n/D, p), (L, n/D)
         n = Xs.shape[1] * D
-        A = jnp.einsum("jni,jnk->jik", Xs, Xs) / n
-        b = jnp.einsum("jni,jn->ji", Xs, ys) / n
+        ein = functools.partial(jnp.einsum, precision=GRAM_PRECISION)
+        A = ein("jni,jnk->jik", Xs, Xs) / n
+        b = ein("jni,jn->ji", Xs, ys) / n
         return (jax.lax.psum(A, data_axis),
                 jax.lax.psum(b, data_axis))
 
