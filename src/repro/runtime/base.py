@@ -300,6 +300,23 @@ def round_jit(fn, **jit_kwargs):
     return _Staged(jax.jit(fn, **jit_kwargs), jit_kwargs)
 
 
+@jax.jit
+def _unstack(bufs):
+    n = jax.tree.leaves(bufs)[0].shape[0]
+    return [jax.tree.map(lambda b: b[i], bufs) for i in range(n)]
+
+
+def unstack_snapshots(bufs) -> list:
+    """Split one record's stacked snapshot buffers (a pytree whose
+    leaves share a leading snapshot axis) into one pytree per snapshot,
+    in a single dispatch under ``solve.snapshots``.  Slicing each
+    snapshot eagerly costs a ``slice`` and a ``squeeze`` dispatch per
+    snapshot, on the host while the device waits; the values are the
+    same bits either way, and the outputs keep the buffers' sharding."""
+    with annotate("solve.snapshots"):
+        return _unstack(bufs)
+
+
 # Worker-data leaves whose axis 1 is the per-task SAMPLE axis — these
 # are the leaves a 2-D runtime shards along the "data" mesh axis.
 # Derived statistics (``gram_A``/``gram_b``) carry no sample axis and
@@ -974,9 +991,9 @@ class ProtocolRuntime:
             for _ in range(rounds):
                 self._replay_round(count_rounds)
             for i, r in enumerate(records):
-                for si, t in enumerate(r.snap_rounds(rounds)):
-                    r.sink.record(
-                        t + 1, jax.tree.map(lambda b: b[si], snaps[i]))
+                for t, value in zip(r.snap_rounds(rounds),
+                                    unstack_snapshots(snaps[i])):
+                    r.sink.record(t + 1, value)
             return state
 
         step = self._compile(body, state, tuple(sharded))

@@ -55,7 +55,7 @@ import numpy as np
 from ..obs.tracing import emit_event as obs_event, trace_span
 from ..train import checkpoint as ckpt_store
 from ..train.checkpoint import CheckpointError
-from .base import _DataEvent, _WireEvent
+from .base import _DataEvent, _WireEvent, unstack_snapshots
 
 # Segment length when ``ckpt_dir`` is given without ``checkpoint_every``
 # — small enough that a preemption loses little work, large enough that
@@ -417,9 +417,9 @@ class SolveCheckpointer:
                 vals0, vdef = jax.tree.flatten(state[r.key])
                 bufs = [jnp.asarray(stored_tree[f"snaps_{i}_{j}"])
                         for j in range(len(vals0))]
-                for si, t in enumerate(np.asarray(ts)):
-                    snaps_hist[i].append((int(t), jax.tree.unflatten(
-                        vdef, [b[si] for b in bufs])))
+                snaps_hist[i].extend(zip(
+                    (int(t) for t in np.asarray(ts)),
+                    unstack_snapshots(jax.tree.unflatten(vdef, bufs))))
             # ledger catch-up: replay the completed rounds from the
             # STORED template so the CommLog continuation is event-for-
             # event identical to the uninterrupted run
@@ -475,9 +475,9 @@ class SolveCheckpointer:
                 for _ in range(seg_len):
                     rt._replay_round(count_rounds)
                 for i, loc in enumerate(local):
-                    for si, t in enumerate(loc):
-                        snaps_hist[i].append(
-                            (t, jax.tree.map(lambda b: b[si], snaps[i])))
+                    if loc:
+                        snaps_hist[i].extend(
+                            zip(loc, unstack_snapshots(snaps[i])))
                 self._persist(rt, e, rounds, state, snaps_hist, records,
                               count_rounds, scan, fresh_hash)
                 self.info["segments_run"] += 1

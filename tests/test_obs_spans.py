@@ -129,6 +129,21 @@ def test_trace_changes_nothing_the_solve_returns(prob, profiled, backend):
     assert bare.comm.summary() == traced.comm.summary()
 
 
+@pytest.mark.parametrize("backend", ["sim", "mesh"])
+def test_snapshot_handoff_one_span_per_record(prob, profiled, backend):
+    """The scanned driver hands a record's snapshots over under one
+    ``solve.snapshots`` span, after the round program's dispatch."""
+    with profiled() as events:
+        repro.solve(prob, method="proxgd", backend=backend, rounds=4,
+                    lam=0.05, scan=True, metrics=True)
+    (call,) = _named(events, "solve")
+    (run,) = _named(events, "solve.run")
+    spans = _named(events, "solve.snapshots")
+    assert len(spans) == 2, spans          # the iterates, the metrics
+    for span in spans:
+        assert _inside(span, call) and span[1] >= run[2], (span, run)
+
+
 def test_eager_driver_stages_once(prob, profiled, cleared):
     with profiled() as events:
         res = repro.solve(prob, method="proxgd", rounds=3, lam=0.05,

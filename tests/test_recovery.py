@@ -24,6 +24,7 @@ import pytest
 import repro
 from repro.core.methods import MTLProblem
 from repro.data.synthetic import SimSpec, generate
+from repro.runtime import base as runtime_base
 from repro.train import checkpoint
 
 
@@ -85,6 +86,35 @@ def test_sim_segmented_and_resumed_bitidentical(tmp_path, scan,
     done = repro.resume(d)
     _assert_identical(base, done)
     assert done.extras["checkpoint"]["segments_run"] == 0
+
+
+@pytest.mark.parametrize("scan", [True, False], ids=["scan", "eager"])
+def test_segments_of_several_snapshots_resume_bitidentical(tmp_path,
+                                                           monkeypatch,
+                                                           scan):
+    """Segments of 5 rounds holding 2, 3 and 1 snapshots (record_every
+    2 of 11): the scanned segment driver hands each segment's snapshots
+    over in one split per record, and a resume from the first segment
+    reproduces the uninterrupted run's iterates exactly."""
+    kw = dict(KW, record_every=2)
+    base = repro.solve(_problem(), backend="sim", scan=scan, **kw)
+    split, calls = runtime_base._unstack, []
+    monkeypatch.setattr(runtime_base, "_unstack",
+                        lambda bufs: calls.append(1) or split(bufs))
+    d = str(tmp_path / "store")
+    seg = repro.solve(_problem(), backend="sim", scan=scan,
+                      checkpoint_every=5, ckpt_dir=d, **kw)
+    _assert_identical(base, seg)
+    assert checkpoint.available_steps(d) == [5, 10, 11]
+    assert len(calls) == (3 if scan else 0)       # one per segment
+
+    for s in (10, 11):
+        os.remove(os.path.join(d, f"step_{s:08d}.npz"))
+    with pytest.warns(UserWarning, match="rolling back"):
+        res = repro.resume(d)
+    _assert_identical(base, res)
+    assert len(res.iterates) == len(base.iterates) == 1 + 6
+    assert res.extras["checkpoint"]["resumed_from"] == 5
 
 
 def test_resume_rejects_config_drift(tmp_path):
