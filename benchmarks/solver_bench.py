@@ -291,10 +291,10 @@ def bench_checkpoint(spec: dict, guard: bool) -> dict:
     persist_times = []
     orig_persist = recovery.SolveCheckpointer._persist
 
-    def probed(self, rt, end, rounds_, state, *rest):
+    def probed(self, end, state, *rest):
         jax.block_until_ready(state)
         t0 = time.perf_counter()
-        out = orig_persist(self, rt, end, rounds_, state, *rest)
+        out = orig_persist(self, end, state, *rest)
         persist_times.append(time.perf_counter() - t0)
         return out
 
@@ -342,7 +342,7 @@ def bench_obs(spec: dict, guard: bool) -> dict:
     program, and compile time is both noisy and R-dependent, so
     end-to-end wall-clock differencing cannot resolve a 5%% per-round
     effect.  Instead the bench captures each variant's COMPILED scan
-    program (hooking ``SimRuntime._compile_scan`` during the solve)
+    program (hooking ``SimRuntime._compile_segment`` during the solve)
     and times warm re-executions of it — pure device steady state, min
     over ``reps`` interleaved runs.  Always asserts the §15 invariant —
     instrumented W and ledger bit-identical to bare — and with
@@ -359,26 +359,31 @@ def bench_obs(spec: dict, guard: bool) -> dict:
                    rounds=rounds, record_every=rounds)
 
     progs: dict = {}
-    orig = SimRuntime._compile_scan
+    orig = SimRuntime._compile_segment
 
-    def capturing(self, body, state, sharded, r, records):
-        fn = orig(self, body, state, sharded, r, records)
-        progs[progs["label"]] = (fn, state)
-        return fn
+    def capturing(self, *args):
+        fn, label = orig(self, *args), progs["label"]
 
-    SimRuntime._compile_scan = capturing
+        def call(state, start, slots):
+            progs[label] = (fn, state, start, slots)
+            return fn(state, start, slots)
+        return call
+
+    SimRuntime._compile_segment = capturing
     try:
         progs["label"] = "bare"
         bare, bare_solve_s = _solve_timed(prob, **base_kw)
         progs["label"] = "inst"
         inst, inst_solve_s = _solve_timed(prob, metrics=True, **base_kw)
     finally:
-        SimRuntime._compile_scan = orig
+        SimRuntime._compile_segment = orig
 
     def timed(label):
-        fn, state = progs[label]
+        fn, state, start, slots = progs[label]
         t0 = time.perf_counter()
-        out = fn(state)             # warm: compiled during the solve
+        state = SimRuntime._shield_donated(state,
+                                           SimRuntime._state_donation())
+        out = fn(state, start, slots)   # warm: compiled during the solve
         jax.block_until_ready(out)
         return time.perf_counter() - t0
 

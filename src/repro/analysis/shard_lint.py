@@ -15,15 +15,15 @@ Three bug classes this repo has actually hit (or dodged narrowly):
 * SHRD002 — a buffer donated to a jitted call and then read again by a
   later equation of the same enclosing program (undefined contents),
   or donated with no output of matching shape/dtype (XLA cannot reuse
-  it, the donation is dead weight).  The scanned driver donates its
+  it, the donation is dead weight).  The round program donates its
   state carry; this proves the shield-copy discipline
   (``_shield_donated``) actually protects every later read.
 * SHRD003 — round-body state whose output avals drift from its input
-  avals (dtype / weak-type promotion, shape change).  Under ``scan``
-  jax rejects a drifting carry outright; the EAGER driver instead
-  silently retraces every round, turning one compile into ``rounds``
-  compiles.  The D=1 weak-type bug fixed in ``ProtocolRuntime.
-  _norm_collective`` is exactly this class.
+  avals (dtype / weak-type promotion, shape change).  The round
+  program is a ``lax.scan`` under every driver, and jax rejects a
+  drifting carry there outright; the lint names the leaf.  The D=1
+  weak-type bug fixed in ``ProtocolRuntime._norm_collective`` is
+  exactly this class.
 """
 from __future__ import annotations
 
@@ -131,7 +131,7 @@ def drift_lint(in_shapes, out_shapes, where: str) -> List[Finding]:
                 "SHRD003",
                 f"state leaf {name} drifts across one round: "
                 f"in shape/dtype/weak_type {sig_i} -> out {sig_o} — the "
-                f"eager driver would silently retrace every round "
+                f"round program's scan carry rejects it "
                 f"(normalize the aval, cf. _norm_collective)", where))
     return findings
 

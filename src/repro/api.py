@@ -59,11 +59,12 @@ def solve(prob, method: str = "dgsp", backend: str = "sim", *,
     data_axis: name of the data mesh axis (2-D mesh backend only).
     rounds: communication rounds, forwarded when given (one-shot
         baselines take none).
-    scan: True (the default inside every solver) fuses the whole round
-        loop into one device-resident ``lax.scan`` dispatch; False runs
-        the eager one-jitted-step-per-round driver.  Ledger, snapshots
-        and results are identical either way
-        (``tests/test_runtime_parity.py``).
+    scan: the dispatch granularity of the one round program.  True
+        (the default inside every solver) runs the whole round loop in
+        one device-resident ``lax.scan`` dispatch (one per
+        ``checkpoint_every`` rounds with ``ckpt_dir``); False dispatches
+        it one round at a time.  Ledger, snapshots and results are
+        identical either way (``tests/test_runtime_parity.py``).
     sv_engine: "lazy" (the default inside the prox-family solvers) runs
         the master's singular-value shrinkage / truncation on the
         warm-started randomized spectral engine

@@ -261,8 +261,8 @@ def _lookup(jitted, args, signature, jit_kwargs):
 class _Staged:
     """A round program that finds its compiled program on each new
     argument signature (:func:`_lookup`), keeps it for the object's
-    later calls (the eager driver's rounds, the segment driver's
-    segments) and dispatches under ``solve.run``."""
+    later calls (the later segments of one solve: one a round under the
+    eager driver) and dispatches under ``solve.run``."""
 
     def __init__(self, jitted, jit_kwargs):
         self.jitted, self.jit_kwargs = jitted, jit_kwargs
@@ -329,16 +329,16 @@ class RecordSpec:
     """Snapshot cadence for one state entry, driver-mode agnostic.
 
     ``sink.record(round, value)`` receives ``state[key]`` after every
-    ``every``-th round (and always after the final round) — host-side
-    per round under the eager driver, from the stacked scan outputs
-    under the scanned driver.  Replaces the old ``on_round`` callback,
-    which could not exist inside a fused ``lax.scan`` round loop.
+    ``every``-th round (and always after the final round), from the
+    round program's stacked outputs, whatever the dispatch granularity.
+    Replaces the old ``on_round`` callback, which could not exist inside
+    a fused ``lax.scan`` round loop.
 
     The recorded entry may be a PYTREE of arrays (e.g. the ``"obs"``
-    round-metrics dict, repro.obs): the scan driver carries one stacked
-    buffer per leaf.  ``run_rounds(record=...)`` accepts a single spec
-    or a sequence of them, so an iterate history and a metrics channel
-    ride the same scan without interfering.
+    round-metrics dict, repro.obs): the program stacks each leaf.
+    ``run_rounds(record=...)`` accepts a single spec or a sequence of
+    them, so an iterate history and a metrics channel ride the same
+    scan without interfering.
     """
     sink: object          # anything with .record(rnd: int, value)
     every: int = 1
@@ -429,11 +429,10 @@ class ProtocolRuntime:
         # the capture, and the initial state is returned unchanged
         self._capture = None
         # when set (repro.runtime.recovery.SolveCheckpointer), run_rounds
-        # hands the whole drive to the segmented resumable driver: the
-        # round loop splits into checkpoint_every-round segments whose
-        # full carry persists between segments, and a preempted solve
-        # restarts from the latest intact segment with a bit-identical
-        # W + ledger continuation (DESIGN.md §12)
+        # starts from the store's latest intact segment and persists the
+        # full carry every checkpoint_every rounds, so a preempted solve
+        # restarts with a bit-identical W + ledger continuation
+        # (DESIGN.md §12)
         self._ckpt = None
 
     # ------------------------------------------------------------------
@@ -569,8 +568,8 @@ class ProtocolRuntime:
         identity branch — and the sim emulation's vmapped collectives —
         PRESERVE it.  Left alone, the same solver carries subtly
         different avals per layout (a weak-typed scalar statistic drifts
-        a scan carry and silently retraces the eager driver), which
-        ``test_runtime_parity`` used to paper over with float tolerance.
+        a scan carry), which ``test_runtime_parity`` used to paper over
+        with float tolerance.
         A same-dtype ``convert_element_type`` is a no-op on values but
         pins the aval, making every layout agree by construction."""
         return jax.lax.convert_element_type(x, jnp.asarray(x).dtype)
@@ -735,10 +734,6 @@ class ProtocolRuntime:
         keep = set(self._data_leaves)
         return {k: v for k, v in data.items() if k in keep}
 
-    def _compile(self, body: RoundBody, state, sharded):
-        """Return step(t:int, state) -> state with data bound as args."""
-        raise NotImplementedError
-
     @staticmethod
     def _as_records(record) -> Tuple[RecordSpec, ...]:
         """Normalize ``run_rounds``'s ``record=`` argument: None, one
@@ -748,14 +743,6 @@ class ProtocolRuntime:
         if isinstance(record, RecordSpec):
             return (record,)
         return tuple(record)
-
-    def _compile_scan(self, body: RoundBody, state, sharded, rounds: int,
-                      records: Tuple[RecordSpec, ...]):
-        """Return fn(state) -> (state, snaps) running ALL rounds in one
-        device-resident ``lax.scan``.  ``snaps`` is one entry per record
-        spec, each a pytree matching ``state[spec.key]`` with a leading
-        snapshot axis; () when ``records`` is empty."""
-        raise NotImplementedError
 
     @staticmethod
     def _snap_write(bufs, value, slot):
@@ -776,139 +763,96 @@ class ProtocolRuntime:
             lambda leaf: jnp.zeros((n_snaps,) + jnp.shape(leaf),
                                    jnp.asarray(leaf).dtype), value)
 
-    def _scan_program(self, body: RoundBody, rounds: int,
-                      records: Tuple[RecordSpec, ...]):
-        """The backend-shared scan core: program(state, data) ->
-        (state, snaps).
+    def _scan_program(self, body: RoundBody, seg_len: int,
+                      layout: Tuple[Tuple[str, int], ...]):
+        """The backend-shared round program: program(state, data, start,
+        slot_of) -> (state, snaps), running ``seg_len`` rounds of
+        ``body`` in one ``lax.scan`` from global round ``start``.
 
-        Snapshots are written into preallocated (n_snaps, ...) buffers
-        (one per recorded leaf) carried through the scan — stacked scan
-        outputs replace the eager driver's host-side record callback,
-        so ``record_every`` histories survive the fusion without
-        materializing every round.  The per-round write slots are
-        derived from the SAME ``snap_rounds`` lists the driver uses to
-        size the buffers and map snapshots back to round numbers — one
-        source of truth for the cadence.
+        Every solve runs it (:meth:`run_rounds`): a scanned solve as one
+        segment of all its rounds, a checkpointed one as a segment per
+        checkpoint, an eager one as a one-round segment per round.  The
+        body sees round index ``start + i`` wherever the segment begins,
+        through the same per-round HLO, so every split of a solve into
+        segments computes the same bits (DESIGN.md §12).  ``start`` and
+        ``slot_of`` — an (n_specs, seg_len) table of snapshot slots, -1
+        where a round takes none — are arguments, not trace constants:
+        every segment of one length and one snapshot count per spec
+        shares one program.
 
-        A spec that snapshots EVERY round (the obs metrics channel)
-        skips the buffer machinery entirely and streams through the
-        scan's stacked ``ys`` output instead: same (rounds, ...) result,
-        but no preallocated carry buffers, no per-round ``cond``, and no
-        slot table — the conditional-write path roughly doubled the
-        compiled program for what is an unconditional copy.
+        ``layout`` is one ``(state key, snapshots in this segment)`` pair
+        per record spec, and each spec takes one of three forms:
+
+        * no snapshot: a ``()`` placeholder (a dynamic update into a
+          zero-length buffer would not even compile);
+        * a snapshot every round (``record_every=1``, the obs channel):
+          streamed through the scan's stacked ``ys`` — no buffer, no
+          per-round ``cond``, no slot table;
+        * otherwise a preallocated (n, ...) buffer per leaf, carried
+          through the scan and written at the slot ``slot_of`` names.
         """
-        snap_lists = [r.snap_rounds(rounds) for r in records]
-        dense = [snap_at == list(range(rounds)) for snap_at in snap_lists]
-        buf_idx = [i for i in range(len(records)) if not dense[i]]
-        slot_rows = []                  # slot_rows[j][t] = snapshot index
-        for i in buf_idx:
-            row = [-1] * rounds
-            for s, t in enumerate(snap_lists[i]):
-                row[t] = s
-            slot_rows.append(row)
-
-        def program(state, data):
-            ks = jnp.arange(rounds, dtype=jnp.int32)
-            if not records:
-                def step(st, k):
-                    return body(k, st, data), None
-                state, _ = jax.lax.scan(step, state, ks)
-                return state, ()
-
-            snaps0 = tuple(
-                self._snap_zeros(len(snap_lists[i]), state[records[i].key])
-                for i in buf_idx)
-            slot_of = (jnp.asarray(slot_rows, jnp.int32)  # (n_buf, rounds)
-                       if buf_idx else None)
-
-            def step(carry, k):
-                st, snaps = carry
-                st = body(k, st, data)
-                snaps = tuple(
-                    self._snap_write(snaps[j], st[records[i].key],
-                                     slot_of[j, k])
-                    for j, i in enumerate(buf_idx))
-                ys = tuple(st[r.key]
-                           for i, r in enumerate(records) if dense[i])
-                return (st, snaps), ys
-
-            (state, snaps), ys = jax.lax.scan(step, (state, snaps0), ks)
-            out, bi, yi = [], iter(snaps), iter(ys)
-            for i in range(len(records)):
-                out.append(next(yi) if dense[i] else next(bi))
-            return state, tuple(out)
-
-        return program
-
-    def _scan_segment_program(self, body: RoundBody, seg_len: int,
-                              seg_records: Tuple[Tuple[str, int], ...]):
-        """The segment core of a RESUMABLE scanned solve: program(state,
-        data, start, slot_of) -> (state, snaps), running ``seg_len``
-        rounds from GLOBAL round index ``start``.
-
-        The round index the body sees is ``start + i`` — the same value
-        an uninterrupted ``_scan_program`` run would feed it — and the
-        per-round W dataflow is the identical HLO, so a segmented solve
-        agrees bit-for-bit with the fused single-scan run (the
-        acceptance invariant of DESIGN.md §12).  ``start`` and the
-        per-round snapshot-slot map ``slot_of`` (a (n_specs, seg_len)
-        array of slot indices or -1) enter as ARGUMENTS, not trace
-        constants, so every segment with equal length and per-spec
-        snapshot counts shares one compile.  ``seg_records`` is one
-        ``(state key, snapshots in this segment)`` pair per record
-        spec; specs with zero snapshots here contribute a () snaps
-        placeholder (a dynamic_update into a zero-length buffer would
-        not even compile).
-        """
-        any_snaps = any(n > 0 for _, n in seg_records)
+        buffered = [0 < n < seg_len for _, n in layout]
 
         def program(state, data, start, slot_of):
             ks = start + jnp.arange(seg_len, dtype=jnp.int32)
-            if not any_snaps:
-                # no snapshot falls inside this segment: skip the snap
-                # write machinery entirely
-                def step(st, k):
-                    return body(k, st, data), None
-                state, _ = jax.lax.scan(step, state, ks)
-                return state, tuple(() for _ in seg_records)
+            bufs0 = tuple(self._snap_zeros(n, state[key]) if b else ()
+                          for (key, n), b in zip(layout, buffered))
 
-            snaps0 = tuple(
-                () if n == 0 else self._snap_zeros(n, state[key])
-                for key, n in seg_records)
-
-            def step(carry, k_slots):
-                k, slot_col = k_slots
-                st, snaps = carry
+            def step(carry, xs):
+                (st, bufs), (k, slots) = carry, xs
                 st = body(k, st, data)
-                snaps = tuple(
-                    snaps[i] if n == 0 else
-                    self._snap_write(snaps[i], st[key], slot_col[i])
-                    for i, (key, n) in enumerate(seg_records))
-                return (st, snaps), None
+                bufs = tuple(
+                    self._snap_write(buf, st[key], slots[i]) if b else buf
+                    for i, ((key, _), b, buf) in enumerate(
+                        zip(layout, buffered, bufs)))
+                ys = tuple(st[key] if n == seg_len else ()
+                           for key, n in layout)
+                return (st, bufs), ys
 
-            (state, snaps), _ = jax.lax.scan(
-                step, (state, snaps0), (ks, jnp.transpose(slot_of)))
-            return state, snaps
+            slots = jnp.transpose(slot_of) if any(buffered) else None
+            (state, bufs), ys = jax.lax.scan(step, (state, bufs0),
+                                             (ks, slots))
+            return state, tuple(y if n == seg_len else buf
+                                for (_, n), buf, y in zip(layout, bufs, ys))
 
         return program
 
-    def _compile_segment(self, body: RoundBody, state, sharded,
-                         seg_len: int,
-                         seg_records: Tuple[Tuple[str, int], ...]):
-        """Return fn(state, start, slot_of) -> (state, snaps) running one
-        ``seg_len``-round segment device-resident (backend-specific)."""
+    def _compile_segment(self, body: RoundBody, state, sharded, data,
+                         seg_len: int, layout: Tuple[Tuple[str, int], ...]):
+        """Return fn(state, start, slot_of) -> (state, snaps) running
+        one :meth:`_scan_program` segment on the device, with ``data``
+        bound as an argument (backend-specific)."""
         raise NotImplementedError
 
     @staticmethod
+    def _segment(records, snap_lists, s: int, e: int, scan: bool):
+        """The rounds each record spec hands back from the segment
+        [s, e), the segment's :meth:`_scan_program` layout and its slot
+        table.
+
+        A scanned segment hands back the spec's snapshots in it; an
+        eager one-round segment hands back its round for every spec,
+        which the driver keeps only on the spec's cadence, so that every
+        round of an eager solve runs one program."""
+        emit = [[t for t in sl if s <= t < e] if scan else list(range(s, e))
+                for sl in snap_lists]
+        slots = np.full((len(emit), e - s), -1, np.int32)
+        for i, ts in enumerate(emit):
+            for j, t in enumerate(ts):
+                slots[i, t - s] = j
+        layout = tuple((r.key, len(ts)) for r, ts in zip(records, emit))
+        return emit, layout, slots
+
+    @staticmethod
     def _state_donation():
-        """argnums donating the state arg of the fused scan call (arg 0).
+        """argnums donating the state arg of a round program (arg 0).
         CPU jit does not support buffer donation; skip there."""
         return () if jax.default_backend() == "cpu" else (0,)
 
     @staticmethod
     def _shield_donated(state, donate):
-        """Copy state leaves once before a donating call.  The scanned
-        driver consumes its ``state`` argument, but callers may still
+        """Copy state leaves once before a donating call.  The round
+        program consumes its ``state`` argument, but callers may still
         hold references to the INITIAL leaves (e.g. the round-0 snapshot
         in an MTLResult) — a one-time (p, m) copy against ``rounds`` of
         in-place carry updates."""
@@ -944,74 +888,102 @@ class ProtocolRuntime:
         the body reads (None = all): leaves outside it are not bound
         into the round loop at all (:meth:`_round_data`).
 
-        ``scan=False`` dispatches one jitted step per round from a host
-        loop; ``scan=True`` fuses the whole round loop into a single
-        device-resident ``lax.scan`` call (donated state buffers, one
-        dispatch per solve).  Both drivers share one accounting story:
-        the body is traced exactly once, the primitive calls recorded
-        during that trace become the per-round communication template,
-        and the driver replays ``template × rounds`` into ``self.comm``
-        — valid because every round of one solver runs the same
-        collectives (the static round structure of all Table-1
-        protocols, DESIGN.md §5), so the ledger is bit-identical across
-        drivers by construction.  ``record`` snapshots state entries on
-        their ``record_every`` cadences in either mode (one RecordSpec
-        or a sequence — e.g. the W iterate history next to the obs
-        round-metrics channel).
+        The one round loop.  It dispatches segments of one device
+        program (:meth:`_scan_program`): ``scan=True`` runs all rounds
+        in one dispatch (one per ``checkpoint_every`` rounds when a
+        checkpointer is attached), ``scan=False`` one dispatch a round.
+        The first dispatch traces the body once; the primitive calls
+        recorded during that trace become the per-round communication
+        template, and the loop replays ``template × rounds`` into
+        ``self.comm`` — valid because every round of one solver runs
+        the same collectives (the static round structure of all
+        Table-1 protocols, DESIGN.md §5), so the ledger is bit-identical
+        whatever the dispatch granularity.  ``record`` snapshots state
+        entries on their ``record_every`` cadences (one RecordSpec or a
+        sequence — e.g. the W iterate history next to the obs
+        round-metrics channel); the sinks receive them after the last
+        round.
 
-        Both drivers work unchanged under 2-D sharding
-        (``data_shards > 1``): the scanned loop sits inside the 2-D
-        ``shard_map`` (or inside the sim emulation's data-axis vmap),
-        tasks-axis collectives replicate across data shards, and the
-        recorded tasks-axis template — hence the CommLog — is
+        With a checkpointer (``self._ckpt``, DESIGN.md §12) the loop
+        starts from its newest intact segment, has it check the traced
+        template against the stored one, and has it persist the carry
+        at every ``checkpoint_every``-round boundary.
+
+        Under 2-D sharding (``data_shards > 1``) the scan sits inside
+        the 2-D ``shard_map`` (or inside the sim emulation's data-axis
+        vmap), tasks-axis collectives replicate across data shards, and
+        the recorded tasks-axis template — hence the CommLog — is
         bit-identical to the 1-D run.
         """
         self._claim()
-        self._template = []
-        self._data_template = []
         self._data_leaves = None if data_leaves is None else \
             tuple(data_leaves)
         records = self._as_records(record)
-        if self._ckpt is not None and self._capture is None:
-            # segmented resumable driver (repro.runtime.recovery): same
-            # per-round program + accounting, with the carry persisted
-            # between checkpoint_every-round segments
-            return self._ckpt.drive(self, rounds, body, state,
-                                    tuple(sharded), records, count_rounds,
-                                    scan)
-        self._recording = True
+        sharded = tuple(sharded)
+        # the data build comes first: its one-per-solve setup accounting
+        # (the 2-D Gram cache) must not depend on how many segments run
+        data = self._round_data()
+        snap_lists = [r.snap_rounds(rounds) for r in records]
         if self._capture is not None:
-            return self._capture_rounds(rounds, body, state, tuple(sharded),
-                                        records, count_rounds, scan)
-        if scan:
-            fn = self._compile_scan(body, state, tuple(sharded), rounds,
-                                    records)
-            state, snaps = fn(state)    # traces once: records the template
-            self._recording = False
-            for _ in range(rounds):
+            return self._capture_rounds(rounds, body, state, sharded, data,
+                                        records, snap_lists, count_rounds,
+                                        scan)
+        ckpt, start = self._ckpt, 0
+        history: List[list] = [[] for _ in records]   # [(round, value)]
+        if ckpt is not None:
+            start, state, history, stored = ckpt.begin(
+                rounds, state, records, count_rounds, scan,
+                getattr(self, "mesh", None))
+            if stored is not None:
+                # ledger catch-up from the STORED template, so the
+                # continuation is event-for-event the uninterrupted run's
+                self._template, self._data_template = stored
+            for _ in range(start):
                 self._replay_round(count_rounds)
-            for i, r in enumerate(records):
-                for t, value in zip(r.snap_rounds(rounds),
-                                    unstack_snapshots(snaps[i])):
-                    r.sink.record(t + 1, value)
-            return state
-
-        step = self._compile(body, state, tuple(sharded))
-        snap_sets = [set(r.snap_rounds(rounds)) for r in records]
-        for t in range(rounds):
-            state = step(t, state)   # first call traces + records
-            self._recording = False
-            self._replay_round(count_rounds)
-            for r, sset in zip(records, snap_sets):
-                if t in sset:
-                    r.sink.record(t + 1, state[r.key])
+        every = max(rounds, 1) if ckpt is None else ckpt.every
+        seg = every if scan else 1
+        snap_sets = [set(sl) for sl in snap_lists]
+        programs = {}
+        for s in range(start, rounds, seg):
+            e = min(s + seg, rounds)
+            emit, layout, slots = self._segment(records, snap_lists, s, e,
+                                                scan)
+            key = (e - s, layout)
+            if key not in programs:
+                programs[key] = self._compile_segment(
+                    body, state, sharded, data, e - s, layout)
+            if s == start:
+                # the first dispatch traces the body, and takes the
+                # caller's leaves: copy them before the program donates
+                state = self._shield_donated(state, self._state_donation())
+                self._template, self._data_template = [], []
+                self._recording = True
+            state, snaps = programs[key](state, s, slots)
+            if self._recording:
+                self._recording = False
+                if ckpt is not None:
+                    ckpt.seal(self._template, self._data_template)
+            for _ in range(e - s):
+                self._replay_round(count_rounds)
+            for ts, snap, sset, hist in zip(emit, snaps, snap_sets, history):
+                if any(t in sset for t in ts):
+                    hist.extend((t, v) for t, v in
+                                zip(ts, unstack_snapshots(snap)) if t in sset)
+            if ckpt is not None and (e % ckpt.every == 0 or e == rounds):
+                ckpt.save(e, state, history)
+        for r, hist in zip(records, history):
+            for t, value in hist:
+                r.sink.record(t + 1, value)
         return state
 
-    def _capture_rounds(self, rounds: int, body: RoundBody, state,
-                        sharded, records, count_rounds: bool, scan: bool):
+    def _capture_rounds(self, rounds: int, body: RoundBody, state, sharded,
+                        data, records, snap_lists, count_rounds: bool,
+                        scan: bool):
         """The static-analysis driver (``repro.analysis``): trace the
-        EXACT program the real driver would execute — same jit / vmap /
-        shard_map wrapping, same donation decision — but never run it.
+        program of the solve's first dispatch — all ``rounds`` under
+        ``scan``, one round otherwise, with the same jit / vmap /
+        shard_map wrapping and donation decision as :meth:`run_rounds`
+        — but never run it.
 
         Tracing executes the round body abstractly, so the primitives
         record the same per-round communication template a real solve
@@ -1023,20 +995,23 @@ class ProtocolRuntime:
         and snapshot sinks receive it as a placeholder so solver
         post-processing stays oblivious.
         """
-        if scan:
-            fn = self._compile_scan(body, state, sharded, rounds, records)
-        else:
-            step = self._compile(body, state, sharded)
-            fn = lambda s: step(0, s)                         # noqa: E731
-        closed, out_shapes = jax.make_jaxpr(fn, return_shape=True)(state)
+        seg_len = rounds if scan else 1
+        _, layout, slots = self._segment(records, snap_lists, 0, seg_len,
+                                         scan)
+        fn = self._compile_segment(body, state, sharded, data, seg_len,
+                                   layout)
+        donate = self._state_donation()
+        self._recording = True
+        closed, out_shapes = jax.make_jaxpr(
+            lambda s: fn(self._shield_donated(s, donate), 0, slots),
+            return_shape=True)(state)
         self._recording = False                  # template recorded above
         for _ in range(rounds):
             self._replay_round(count_rounds)
-        for r in records:
-            for t in r.snap_rounds(rounds):
+        for r, sl in zip(records, snap_lists):
+            for t in sl:
                 r.sink.record(t + 1, state[r.key])
-        self._capture.absorb(self, closed, state,
-                             out_shapes[0] if scan else out_shapes,
+        self._capture.absorb(self, closed, state, out_shapes[0],
                              rounds=rounds, scan=scan)
         return state
 

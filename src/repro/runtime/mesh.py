@@ -224,7 +224,7 @@ class MeshRuntime(ProtocolRuntime):
             return P(*([None] * (nd - 1)), self.axis)  # task columns last
         return P(*([None] * nd))
 
-    def _specs(self, state, sharded):
+    def _specs(self, state, sharded, data):
         axis = self.axis
 
         # state entries may be pytrees (a solver's spectral-engine
@@ -235,7 +235,6 @@ class MeshRuntime(ProtocolRuntime):
             shard_it = n in sharded
             state_specs[n] = jax.tree.map(
                 lambda leaf, s=shard_it: self._leaf_spec(leaf, s), v)
-        data = self._round_data()
 
         def data_spec(name, v):
             # every data leaf is a per-task stack: sharded along axis 0;
@@ -248,7 +247,7 @@ class MeshRuntime(ProtocolRuntime):
             return P(axis, *rest)
 
         data_specs = {n: data_spec(n, v) for n, v in data.items()}
-        return state_specs, data, data_specs
+        return state_specs, data_specs
 
     # ------------------------------------------------------------------
     # multi-controller input binding
@@ -289,18 +288,6 @@ class MeshRuntime(ProtocolRuntime):
             return jnp.asarray(x, jnp.int32)
         return self._put_global(np.asarray(x, np.int32), spec)
 
-    def _compile(self, body, state, sharded):
-        state_specs, data, data_specs = self._specs(state, sharded)
-        data = self._bind_data(data, data_specs)
-        fn = shard_map(lambda k, s, d: body(k, s, d),
-                       mesh=self.mesh,
-                       in_specs=(P(), state_specs, data_specs),
-                       out_specs=state_specs,
-                       check_vma=False)
-        step = round_jit(fn)
-        return lambda t, s: step(self._bind_scalar(t),
-                                 self._bind_state(s, sharded), data)
-
     @staticmethod
     def _snaps_spec(entry_spec):
         """PartitionSpecs of one recorded entry's stacked snapshot
@@ -309,41 +296,18 @@ class MeshRuntime(ProtocolRuntime):
         return jax.tree.map(lambda ls: P(None, *ls), entry_spec,
                             is_leaf=lambda x: isinstance(x, P))
 
-    def _compile_scan(self, body, state, sharded, rounds, records):
-        state_specs, data, data_specs = self._specs(state, sharded)
+    def _compile_segment(self, body, state, sharded, data, seg_len, layout):
+        state_specs, data_specs = self._specs(state, sharded, data)
         data = self._bind_data(data, data_specs)
-        program = self._scan_program(body, rounds, records)
-        snaps_spec = tuple(self._snaps_spec(state_specs[r.key])
-                           for r in records)
-        fn = shard_map(program,
-                       mesh=self.mesh,
-                       in_specs=(state_specs, data_specs),
-                       out_specs=(state_specs, snaps_spec),
-                       check_vma=False)
-        donate = self._state_donation()
-        step = round_jit(fn, donate_argnums=donate)
-        return lambda s: step(
-            self._shield_donated(self._bind_state(s, sharded), donate),
-            data)
-
-    def _compile_segment(self, body, state, sharded, seg_len, seg_records):
-        state_specs, data, data_specs = self._specs(state, sharded)
-        data = self._bind_data(data, data_specs)
-        program = self._scan_segment_program(body, seg_len, seg_records)
-        any_snaps = any(n > 0 for _, n in seg_records)
-        snaps_spec = tuple(
-            () if not any_snaps or n == 0
-            else self._snaps_spec(state_specs[key])
-            for key, n in seg_records)
-        fn = shard_map(program,
+        snaps_spec = tuple(self._snaps_spec(state_specs[key]) if n else ()
+                           for key, n in layout)
+        fn = shard_map(self._scan_program(body, seg_len, layout),
                        mesh=self.mesh,
                        in_specs=(state_specs, data_specs, P(),
                                  P(None, None)),
                        out_specs=(state_specs, snaps_spec),
                        check_vma=False)
-        donate = self._state_donation()
-        step = round_jit(fn, donate_argnums=donate)
+        step = round_jit(fn, donate_argnums=self._state_donation())
         return lambda s, start, slots: step(
-            self._shield_donated(self._bind_state(s, sharded), donate),
-            data, self._bind_scalar(start),
+            self._bind_state(s, sharded), data, self._bind_scalar(start),
             self._bind_scalar(np.asarray(slots), P(None, None)))

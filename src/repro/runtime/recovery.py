@@ -6,12 +6,15 @@ across rounds (the static round structure of every Table-1 protocol).
 That makes a killed solve resumable EXACTLY: persist the full carry —
 solver state, spectral-engine carry, snapshot history, ledger cursor +
 comm-template — at segment boundaries, and replay the remaining rounds
-from the same round indices.  The segmented program feeds the body the
-same ``k`` values through the identical per-round HLO as the fused
-single-scan run, so the final ``W``, the CommLog ledger, and the
-measured ``collective_floats_per_chip`` of a resumed solve are
-bit-identical to an uninterrupted one (tests/test_recovery.py asserts
-this across sim/mesh × eager/scan × 1-D/2-D).
+from the same round indices.  ``ProtocolRuntime.run_rounds`` runs
+every solve as segments of one round program, which feeds the body the
+same ``k`` values through the same per-round HLO however the rounds are
+split, so the final ``W``, the CommLog ledger, and the measured
+``collective_floats_per_chip`` of a resumed solve are bit-identical to
+an uninterrupted one (tests/test_recovery.py asserts this across
+sim/mesh × eager/scan × 1-D/2-D).  This module holds the store side:
+what a segment persists, how a solve restores it, and the checks that
+refuse a drifted configuration.
 
 Layout of a solve store (one directory per solve)::
 
@@ -88,14 +91,14 @@ def _read_json(path: str) -> Dict[str, Any]:
         return json.load(f)
 
 
-def _host_leaf(rt, x) -> np.ndarray:
+def _host_leaf(mesh, x) -> np.ndarray:
     """Fetch one (possibly mesh-sharded) array to a full host copy.
     Under multi-controller jax a sharded global array is not fully
     addressable from one process; an identity jit with replicated
-    out_shardings all-gathers it first."""
+    out_shardings over its ``mesh`` all-gathers it first."""
     if isinstance(x, jax.Array) and not x.is_fully_addressable:
         from jax.sharding import NamedSharding, PartitionSpec
-        sh = NamedSharding(rt.mesh, PartitionSpec())
+        sh = NamedSharding(mesh, PartitionSpec())
         x = jax.jit(lambda a: a, out_shardings=sh)(x)
     return np.asarray(x)
 
@@ -111,14 +114,6 @@ def template_hash(template: List[_WireEvent],
          [dataclasses.asdict(e) for e in data_template]],
         sort_keys=True).encode()
     return hashlib.sha256(blob).hexdigest()
-
-
-def segment_bounds(rounds: int, every: int) -> List[Tuple[int, int]]:
-    """The (start, end) round ranges of each checkpointed segment."""
-    if every < 1:
-        raise ValueError(f"checkpoint_every={every} must be >= 1")
-    starts = list(range(0, rounds, every))
-    return [(s, min(s + every, rounds)) for s in starts]
 
 
 # ----------------------------------------------------------------------
@@ -250,17 +245,17 @@ def _touch_manifest_latest(ckpt_dir: str, step: int) -> None:
 
 
 # ----------------------------------------------------------------------
-# the segmented driver
+# the solve's store
 # ----------------------------------------------------------------------
 class SolveCheckpointer:
-    """Drives ONE solve's round loop in checkpointed segments.
+    """The store side of ONE checkpointed solve.
 
     Attached to a runtime as ``rt._ckpt`` by ``repro.solve(...,
-    ckpt_dir=)``; ``run_rounds`` delegates its whole drive here.  The
-    drive preserves the uninterrupted drivers' semantics exactly: same
-    round indices into the body, same single-trace template accounting,
-    same snapshot cadence — plus a persisted carry at every segment
-    boundary and a bit-identical restart from the newest intact one.
+    ckpt_dir=)``.  ``run_rounds`` runs the rounds in segments of
+    ``every`` and calls three methods: :meth:`begin` before the first
+    segment (check and restore the newest intact segment, if any),
+    :meth:`seal` after the first trace (the template-hash check) and
+    :meth:`save` at every segment's end (persist the full carry).
     """
 
     def __init__(self, ckpt_dir: str, every: int = DEFAULT_SEGMENT,
@@ -271,6 +266,7 @@ class SolveCheckpointer:
         self.every = int(every)
         self.keep = keep
         self._resume: Optional[Dict[str, Any]] = None
+        self._solve: Dict[str, Any] = {}
         self.info: Dict[str, Any] = {"dir": ckpt_dir, "every": self.every,
                                      "resumed_from": 0, "segments_run": 0,
                                      "skipped_corrupt": [],
@@ -308,22 +304,113 @@ class SolveCheckpointer:
                   skipped_corrupt=list(skipped))
         return True
 
-    # -- persistence ----------------------------------------------------
-    def _persist(self, rt, end: int, rounds: int, state, snaps_hist,
-                 records, count_rounds: bool, scan: bool,
-                 tmpl_hash: str) -> None:
+    # -- the three calls of run_rounds ----------------------------------
+    def begin(self, rounds: int, state, records, count_rounds: bool,
+              scan: bool, mesh=None):
+        """Open the solve on this store.
+
+        Returns ``(rounds done, state, snapshot history, template)``:
+        with no stored segment, ``(0, state, empty histories, None)``;
+        otherwise the stored carry in the solver-built state's treedef,
+        each record spec's ``[(round, value)]`` history so far, and the
+        stored per-round ``(wire, data)`` template whose replay catches
+        the ledger up.  A stored segment of another round count,
+        snapshot cadence or carry refuses to resume (config drift)."""
+        self._solve = {"rounds": int(rounds), "records": tuple(records),
+                       "count_rounds": bool(count_rounds),
+                       "scan": bool(scan), "mesh": mesh,
+                       "stored_hash": None}
+        history: List[List[Tuple[int, Any]]] = [[] for _ in records]
+        if self._resume is None:
+            return 0, state, history, None
+        meta = self._resume["meta"]
+        if meta["rounds"] != rounds:
+            raise CheckpointError(
+                f"checkpoint in {self.ckpt_dir} was written by a "
+                f"{meta['rounds']}-round solve; this solve runs "
+                f"{rounds} rounds — config drift, refusing to resume")
+        want_rec = [{"every": r.every, "key": r.key}
+                    for r in records] or None
+        got_rec = meta["record"]
+        if isinstance(got_rec, dict):     # pre-multi-spec store
+            got_rec = [got_rec]
+        if got_rec != want_rec:
+            raise CheckpointError(
+                f"checkpoint snapshot cadence {meta['record']} does "
+                f"not match this solve's {want_rec} — config drift")
+        # restore the carry into the solver-built state's treedef
+        leaves0, treedef = jax.tree.flatten(state)
+        loaded = self._resume["carry"]
+        if len(loaded) != len(leaves0):
+            raise CheckpointError(
+                f"checkpoint carry has {len(loaded)} leaves; the "
+                f"solver built {len(leaves0)} — config drift")
+        news = []
+        for a, b in zip(leaves0, loaded):
+            b = jnp.asarray(b)
+            if (jnp.shape(a) != jnp.shape(b)
+                    or jnp.asarray(a).dtype != b.dtype):
+                raise CheckpointError(
+                    f"checkpoint carry leaf {jnp.shape(b)}/{b.dtype} "
+                    f"does not match solver state "
+                    f"{jnp.shape(a)}/{jnp.asarray(a).dtype}")
+            news.append(b)
+        state = jax.tree.unflatten(treedef, news)
+        # snapshot histories up to the resume point
+        stored_tree = self._resume.get("tree") or {}
+        for i, r in enumerate(records):
+            ts = stored_tree.get(f"snap_rounds_{i}")
+            if ts is None:
+                continue
+            vals0, vdef = jax.tree.flatten(state[r.key])
+            bufs = [jnp.asarray(stored_tree[f"snaps_{i}_{j}"])
+                    for j in range(len(vals0))]
+            history[i].extend(zip(
+                (int(t) for t in np.asarray(ts)),
+                unstack_snapshots(jax.tree.unflatten(vdef, bufs))))
+        self._solve["stored_hash"] = meta["template_hash"]
+        template = ([_WireEvent(**d) for d in meta["template"]],
+                    [_DataEvent(**d) for d in meta["data_template"]])
+        return meta["rounds_done"], state, history, template
+
+    def seal(self, template: List[_WireEvent],
+             data_template: List[_DataEvent]) -> None:
+        """Take the per-round template the solve's first trace recorded;
+        a resumed solve must reproduce the stored one's hash."""
+        fresh = template_hash(template, data_template)
+        stored = self._solve["stored_hash"]
+        if stored is not None and fresh != stored:
+            raise CheckpointError(
+                f"resumed solve traced a DIFFERENT per-round "
+                f"communication template (hash {fresh[:12]}… vs "
+                f"stored {stored[:12]}…) — the protocol changed "
+                f"between the killed solve and this resume; the "
+                f"ledger continuation would be meaningless")
+        self._solve.update(template=list(template),
+                           data_template=list(data_template),
+                           template_hash=fresh)
+
+    def save(self, end: int, state, history) -> None:
+        """Persist the carry after round ``end``: the state, every
+        record spec's snapshot history and the sealed template."""
+        self._persist(end, state, history)
+        self.info["segments_run"] += 1
+
+    def _persist(self, end: int, state, history) -> None:
+        solve = self._solve
+        rounds, records, mesh = solve["rounds"], solve["records"], \
+            solve["mesh"]
         final = end == rounds
         if is_primary():
             with trace_span("ckpt.save", step=int(end), final=bool(final),
                             ckpt_dir=self.ckpt_dir):
                 leaves = jax.tree.flatten(state)[0]
                 tree: Dict[str, Any] = {
-                    "carry": [_host_leaf(rt, x) for x in leaves]}
+                    "carry": [_host_leaf(mesh, x) for x in leaves]}
                 # per-spec snapshot histories: the recorded value may be
                 # a pytree, so each spec stores its snap rounds plus one
                 # stacked array per flattened leaf
-                for i, _ in enumerate(records):
-                    hist = snaps_hist[i]
+                for i, hist in enumerate(history):
                     if not hist:
                         continue
                     tree[f"snap_rounds_{i}"] = np.asarray(
@@ -331,20 +418,20 @@ class SolveCheckpointer:
                     flat = [jax.tree.flatten(v)[0] for _, v in hist]
                     for j in range(len(flat[0])):
                         tree[f"snaps_{i}_{j}"] = np.stack(
-                            [_host_leaf(rt, fs[j]) for fs in flat])
+                            [_host_leaf(mesh, fs[j]) for fs in flat])
                 meta = {
                     "version": 1,
                     "rounds": int(rounds),
                     "rounds_done": int(end),
-                    "count_rounds": bool(count_rounds),
-                    "scan": bool(scan),
+                    "count_rounds": solve["count_rounds"],
+                    "scan": solve["scan"],
                     "record": [{"every": r.every, "key": r.key}
                                for r in records] or None,
                     "template": [dataclasses.asdict(e)
-                                 for e in rt._template],
+                                 for e in solve["template"]],
                     "data_template": [dataclasses.asdict(e)
-                                      for e in rt._data_template],
-                    "template_hash": tmpl_hash,
+                                      for e in solve["data_template"]],
+                    "template_hash": solve["template_hash"],
                 }
                 tree["meta_json"] = np.frombuffer(
                     json.dumps(meta, sort_keys=True).encode(),
@@ -356,153 +443,6 @@ class SolveCheckpointer:
         # politely pick the writer), after the store write is durable
         ckpt_store._fire("segment_saved", step=end, ckpt_dir=self.ckpt_dir,
                          final=final)
-
-    # -- the drive ------------------------------------------------------
-    def drive(self, rt, rounds: int, body, state, sharded, records,
-              count_rounds: bool, scan: bool):
-        # data build first: its one-per-solve Gram-cache accounting must
-        # not depend on how many segments execute (a resume with zero
-        # rounds left still charges setup, like any solve)
-        rt._round_data()
-
-        records = tuple(records)
-        snap_lists = [r.snap_rounds(rounds) for r in records]
-        # per-spec snapshot histories: snaps_hist[i] = [(round t, value)]
-        snaps_hist: List[List[Tuple[int, Any]]] = [[] for _ in records]
-        start = 0
-        stored_hash = None
-
-        if self._resume is not None:
-            meta = self._resume["meta"]
-            if meta["rounds"] != rounds:
-                raise CheckpointError(
-                    f"checkpoint in {self.ckpt_dir} was written by a "
-                    f"{meta['rounds']}-round solve; this solve runs "
-                    f"{rounds} rounds — config drift, refusing to resume")
-            want_rec = [{"every": r.every, "key": r.key}
-                        for r in records] or None
-            got_rec = meta["record"]
-            if isinstance(got_rec, dict):     # pre-multi-spec store
-                got_rec = [got_rec]
-            if got_rec != want_rec:
-                raise CheckpointError(
-                    f"checkpoint snapshot cadence {meta['record']} does "
-                    f"not match this solve's {want_rec} — config drift")
-            start = meta["rounds_done"]
-            stored_hash = meta["template_hash"]
-            # restore the carry into the solver-built state's treedef
-            leaves0, treedef = jax.tree.flatten(state)
-            loaded = self._resume["carry"]
-            if len(loaded) != len(leaves0):
-                raise CheckpointError(
-                    f"checkpoint carry has {len(loaded)} leaves; the "
-                    f"solver built {len(leaves0)} — config drift")
-            news = []
-            for a, b in zip(leaves0, loaded):
-                b = jnp.asarray(b)
-                if (jnp.shape(a) != jnp.shape(b)
-                        or jnp.asarray(a).dtype != b.dtype):
-                    raise CheckpointError(
-                        f"checkpoint carry leaf {jnp.shape(b)}/{b.dtype} "
-                        f"does not match solver state "
-                        f"{jnp.shape(a)}/{jnp.asarray(a).dtype}")
-                news.append(b)
-            state = jax.tree.unflatten(treedef, news)
-            # snapshot histories up to the resume point
-            stored_tree = self._resume.get("tree") or {}
-            for i, r in enumerate(records):
-                ts = stored_tree.get(f"snap_rounds_{i}")
-                if ts is None:
-                    continue
-                vals0, vdef = jax.tree.flatten(state[r.key])
-                bufs = [jnp.asarray(stored_tree[f"snaps_{i}_{j}"])
-                        for j in range(len(vals0))]
-                snaps_hist[i].extend(zip(
-                    (int(t) for t in np.asarray(ts)),
-                    unstack_snapshots(jax.tree.unflatten(vdef, bufs))))
-            # ledger catch-up: replay the completed rounds from the
-            # STORED template so the CommLog continuation is event-for-
-            # event identical to the uninterrupted run
-            rt._template = [_WireEvent(**d) for d in meta["template"]]
-            rt._data_template = [_DataEvent(**d)
-                                 for d in meta["data_template"]]
-            for _ in range(start):
-                rt._replay_round(count_rounds)
-
-        fresh_hash = stored_hash          # until a fresh trace overwrites
-        traced = False
-
-        def after_first_trace():
-            nonlocal fresh_hash, traced
-            rt._recording = False
-            traced = True
-            fresh_hash = template_hash(rt._template, rt._data_template)
-            if stored_hash is not None and fresh_hash != stored_hash:
-                raise CheckpointError(
-                    f"resumed solve traced a DIFFERENT per-round "
-                    f"communication template (hash {fresh_hash[:12]}… vs "
-                    f"stored {stored_hash[:12]}…) — the protocol changed "
-                    f"between the killed solve and this resume; the "
-                    f"ledger continuation would be meaningless")
-
-        segs = [(s, e) for s, e in segment_bounds(rounds, self.every)
-                if e > start]
-        if segs:
-            rt._template = []
-            rt._data_template = []
-            rt._recording = True
-
-        if scan:
-            seg_fns: Dict[Tuple[int, Tuple[int, ...]], Any] = {}
-            for s, e in segs:
-                s = max(s, start)
-                seg_len = e - s
-                local = [[t for t in snap_lists[i] if s <= t < e]
-                         for i in range(len(records))]
-                slots = np.full((len(records), seg_len), -1, np.int32)
-                for i, loc in enumerate(local):
-                    for si, t in enumerate(loc):
-                        slots[i, t - s] = si
-                key = (seg_len, tuple(len(loc) for loc in local))
-                if key not in seg_fns:
-                    seg_fns[key] = rt._compile_segment(
-                        body, state, sharded, seg_len,
-                        tuple((r.key, len(loc))
-                              for r, loc in zip(records, local)))
-                state, snaps = seg_fns[key](state, s, slots)
-                if not traced:
-                    after_first_trace()
-                for _ in range(seg_len):
-                    rt._replay_round(count_rounds)
-                for i, loc in enumerate(local):
-                    if loc:
-                        snaps_hist[i].extend(
-                            zip(loc, unstack_snapshots(snaps[i])))
-                self._persist(rt, e, rounds, state, snaps_hist, records,
-                              count_rounds, scan, fresh_hash)
-                self.info["segments_run"] += 1
-        else:
-            step = rt._compile(body, state, sharded) if segs else None
-            bset = {e for _, e in segs}
-            snapsets = [set(sl) for sl in snap_lists]
-            for t in range(start, rounds):
-                state = step(t, state)
-                if not traced:
-                    after_first_trace()
-                rt._replay_round(count_rounds)
-                for i, r in enumerate(records):
-                    if t in snapsets[i]:
-                        snaps_hist[i].append((t, state[r.key]))
-                if t + 1 in bset:
-                    self._persist(rt, t + 1, rounds, state, snaps_hist,
-                                  records, count_rounds, scan, fresh_hash)
-                    self.info["segments_run"] += 1
-
-        rt._recording = False
-        for i, r in enumerate(records):
-            for t, v in sorted(snaps_hist[i], key=lambda kv: kv[0]):
-                r.sink.record(t + 1, v)
-        return state
 
 
 # ----------------------------------------------------------------------

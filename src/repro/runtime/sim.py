@@ -116,62 +116,25 @@ class SimRuntime(ProtocolRuntime):
     # ------------------------------------------------------------------
     # drivers
     # ------------------------------------------------------------------
-    def _compile(self, body, state, sharded):
+    def _compile_segment(self, body, state, sharded, data, seg_len, layout):
         # Data enters as jit ARGUMENTS (not closure constants) so XLA
         # does not constant-fold per-task Gram matrices at compile time.
-        data = self._round_data()
+        program = self._scan_program(body, seg_len, layout)
         if self.data_shards == 1:
-            @round_jit
-            def step(k, state, data):
-                return body(k, state, data)
+            step = round_jit(program, donate_argnums=self._state_donation())
         else:
-            axes = self._data_in_axes(data)
-
-            @round_jit
-            def step(k, state, data):
-                # axis_size keeps the emulated data axis alive even
-                # when pruning left no sample leaves to map over
-                # (gram-only round bodies, run_rounds(data_leaves=...))
-                out = jax.vmap(lambda d: body(k, state, d),
-                               in_axes=(axes,), out_axes=0,
-                               axis_name=self.data_axis,
-                               axis_size=self.data_shards)(data)
-                return self._unreplicate(out)
-
-        return lambda t, s: step(jnp.int32(t), s, data)
-
-    def _compile_scan(self, body, state, sharded, rounds, records):
-        program = self._scan_program(body, rounds, records)
-        data = self._round_data()
-        if self.data_shards == 1:
-            donate = self._state_donation()
-            step = round_jit(program, donate_argnums=donate)
-            return lambda s: step(self._shield_donated(s, donate), data)
-
-        axes = self._data_in_axes(data)
-        vprog = jax.vmap(program, in_axes=(None, axes), out_axes=0,
-                         axis_name=self.data_axis,
-                         axis_size=self.data_shards)
-        # no donation: the emulated program's outputs are (D, ...)
-        # batched, so the (global-shaped) input buffers cannot be reused
-        step = round_jit(lambda s, d: self._unreplicate(vprog(s, d)))
-        return lambda s: step(s, data)
-
-    def _compile_segment(self, body, state, sharded, seg_len, seg_records):
-        program = self._scan_segment_program(body, seg_len, seg_records)
-        data = self._round_data()
-        if self.data_shards == 1:
-            donate = self._state_donation()
-            step = round_jit(program, donate_argnums=donate)
-            return lambda s, start, slots: step(
-                self._shield_donated(s, donate), data,
-                jnp.int32(start), jnp.asarray(slots, jnp.int32))
-
-        axes = self._data_in_axes(data)
-        vprog = jax.vmap(program, in_axes=(None, axes, None, None),
-                         out_axes=0, axis_name=self.data_axis,
-                         axis_size=self.data_shards)
-        step = round_jit(lambda s, d, k0, sl: self._unreplicate(
-            vprog(s, d, k0, sl)))
+            # axis_size keeps the emulated data axis alive even when
+            # pruning left no sample leaves to map over (gram-only round
+            # bodies, run_rounds(data_leaves=...))
+            vprog = jax.vmap(program,
+                             in_axes=(None, self._data_in_axes(data), None,
+                                      None),
+                             out_axes=0, axis_name=self.data_axis,
+                             axis_size=self.data_shards)
+            # no donation: the emulated program's outputs are (D, ...)
+            # batched, so the (global-shaped) input buffers cannot be
+            # reused
+            step = round_jit(lambda s, d, k0, sl: self._unreplicate(
+                vprog(s, d, k0, sl)))
         return lambda s, start, slots: step(
             s, data, jnp.int32(start), jnp.asarray(slots, jnp.int32))

@@ -93,9 +93,10 @@ def test_segments_of_several_snapshots_resume_bitidentical(tmp_path,
                                                            monkeypatch,
                                                            scan):
     """Segments of 5 rounds holding 2, 3 and 1 snapshots (record_every
-    2 of 11): the scanned segment driver hands each segment's snapshots
-    over in one split per record, and a resume from the first segment
-    reproduces the uninterrupted run's iterates exactly."""
+    2 of 11): a scanned segment hands its snapshots over in one split
+    per record, an eager one-round segment in one split per snapshot,
+    and a resume from the first segment reproduces the uninterrupted
+    run's iterates exactly."""
     kw = dict(KW, record_every=2)
     base = repro.solve(_problem(), backend="sim", scan=scan, **kw)
     split, calls = runtime_base._unstack, []
@@ -106,7 +107,8 @@ def test_segments_of_several_snapshots_resume_bitidentical(tmp_path,
                       checkpoint_every=5, ckpt_dir=d, **kw)
     _assert_identical(base, seg)
     assert checkpoint.available_steps(d) == [5, 10, 11]
-    assert len(calls) == (3 if scan else 0)       # one per segment
+    # one per segment; one per snapshot round under the eager driver
+    assert len(calls) == (3 if scan else 6)
 
     for s in (10, 11):
         os.remove(os.path.join(d, f"step_{s:08d}.npz"))

@@ -164,6 +164,20 @@ def test_eager_driver_misses_once_per_solve():
     assert (info.misses, info.hits) == (1, 1)
 
 
+@pytest.mark.parametrize("scan", [True, False], ids=["scan", "eager"])
+def test_plain_and_checkpointed_share_one_program(tmp_path, scan):
+    """A checkpointed solve whose one segment spans every round runs the
+    program of the same solve without a checkpoint: one round program
+    is staged between them, and W comes out bit for bit the same."""
+    kw = {**HP, "rounds": 4, "scan": scan}
+    plain = repro.solve(_prob(0), backend="sim", **kw)
+    ckpt = repro.solve(_prob(0), backend="sim", checkpoint_every=4,
+                       ckpt_dir=str(tmp_path / "store"), **kw)
+    assert base.cache_info().misses == 1
+    assert ckpt.extras["checkpoint"]["segments_run"] == 1
+    np.testing.assert_array_equal(np.asarray(plain.W), np.asarray(ckpt.W))
+
+
 def test_counters_agree_with_the_span_attribute():
     ring = default_tracer()
     before = len(ring.events())
